@@ -11,6 +11,7 @@ import sys
 import traceback
 
 from benchmarks.common import header
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "async_aipm": "benchmarks.bench_async_aipm",
@@ -32,6 +33,7 @@ SUITES = {
 
 def main() -> None:
     wanted = sys.argv[1:] or list(SUITES)
+    enable_compile_cache()
     header()
     failures = []
     ran = set()

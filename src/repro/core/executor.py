@@ -98,7 +98,7 @@ class ExecutionContext:
         self.proxy_hits = 0         # rows the proxy answered (accept|reject)
         self.escalated_rows = 0     # rows escalated to the exact φ
         self.cascade_chunks = 0     # chunks routed through the cascade path
-        self._pushdown_memo: Dict[int, Any] = {}   # plan id -> index matches
+        self._pushdown_memo: Dict[Any, Any] = {}   # plan key -> index matches
         self._func_memo: Dict[int, Any] = {}       # expr id -> blob tag
         #: per-query time budget shared with every other leg of the same
         #: query (shard streams, hedge races); None = no deadline, and every
@@ -1279,21 +1279,30 @@ def _try_var_var_pushdown(plan: lp.SemanticFilter, le: SubProp, re_: SubProp,
     run ONE batched ``search_many`` over the chunk's distinct query vectors,
     and keep rows whose indexed-side blob lands in its query's
     above-threshold neighbor set.  Replaces per-row extraction of the
-    indexed side with index scans (paper §VI-B2 pushdown, batched)."""
+    indexed side with index scans (paper §VI-B2 pushdown, batched).
+
+    When both sides are indexed, the side with fewer distinct blobs in the
+    chunk is the query side: a one-row anchor joined against a whole label
+    then costs one search instead of one per row.  Each query blob's
+    neighbor set is memoized per plan node, so the streaming driver searches
+    it once across chunks."""
     n = _rows(child)
-    idx_expr = query_expr = None
+    best = None
     for a, b in ((le, re_), (re_, le)):
         cand = ctx.db.indexes.get(a.sub_key)
-        if cand is not None and cand.serial == ctx.registry.serial(a.sub_key):
-            index, idx_expr, query_expr = cand, a, b
-            break
-    if idx_expr is None:
+        if cand is None or cand.serial != ctx.registry.serial(a.sub_key):
+            continue
+        try:
+            sides = (_blob_ids_for(a.base, child, ctx),
+                     _blob_ids_for(b.base, child, ctx))
+        except TypeError:
+            return None
+        n_query = len(np.unique(sides[1]))
+        if best is None or n_query < best[0]:
+            best = (n_query, cand, a, b) + sides
+    if best is None:
         return None
-    try:
-        corp_bids = _blob_ids_for(idx_expr.base, child, ctx)
-        q_bids = _blob_ids_for(query_expr.base, child, ctx)
-    except TypeError:
-        return None
+    _, index, idx_expr, query_expr, corp_bids, q_bids = best
     ctx.index_hits += 1
     # self-similarity (`x ~: x`): sim(φ, φ) = 1 -- rows with a blob pass
     if idx_expr == query_expr:
@@ -1302,16 +1311,20 @@ def _try_var_var_pushdown(plan: lp.SemanticFilter, le: SubProp, re_: SubProp,
     keep = np.zeros(n, bool)
     valid = (q_bids >= 0) & (corp_bids >= 0)
     uniq, rep, inv = np.unique(q_bids, return_index=True, return_inverse=True)
-    live = uniq >= 0
-    if live.any():
-        rep_rows = {k: v[rep[live]] for k, v in child.items()}
+    memo = ctx._pushdown_memo.setdefault(
+        (id(plan), idx_expr.sub_key, query_expr.sub_key), {})
+    live = np.nonzero(uniq >= 0)[0]
+    todo = [u for u in live if int(uniq[u]) not in memo]
+    if todo:
+        rep_rows = {k: v[rep[todo]] for k, v in child.items()}
         qvecs = np.asarray(eval_subprop(query_expr, rep_rows, ctx),
-                           np.float32).reshape(int(live.sum()), -1)
-        matches = _index_matches(index, qvecs, ctx)
-        for u, match in zip(np.nonzero(live)[0], matches):
-            sel = (inv == u) & valid
-            if sel.any():
-                keep[sel] = np.isin(corp_bids[sel], match)
+                           np.float32).reshape(len(todo), -1)
+        for u, match in zip(todo, _index_matches(index, qvecs, ctx)):
+            memo[int(uniq[u])] = match
+    for u in live:
+        sel = (inv == u) & valid
+        if sel.any():
+            keep[sel] = np.isin(corp_bids[sel], memo[int(uniq[u])])
     return {k: v[keep] for k, v in child.items()}
 
 

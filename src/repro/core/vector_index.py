@@ -1032,17 +1032,11 @@ class IVFIndex:
             if n_real == 0:
                 continue
             k_eff = min(k, n_real)
-            pad = (-n_real) % self.cfg.block_n
-            if pad:
-                corpus = np.concatenate(
-                    [corpus, np.zeros((pad, corpus.shape[1]), np.float32)])
-            vals, idx = ivf_scan_topk(
-                jnp.asarray(queries[qsel]), jnp.asarray(corpus), k_eff,
-                metric=self.cfg.metric, block_n=self.cfg.block_n,
-                n_valid=n_real)
-            out_v[qsel[:, None], np.arange(k_eff)[None, :]] = np.asarray(vals)
-            out_i[qsel[:, None], np.arange(k_eff)[None, :]] = \
-                ids[np.asarray(idx)]
+            vals, idx = ivf_scan_topk(queries[qsel], corpus, k_eff,
+                                      metric=self.cfg.metric,
+                                      block_n=self.cfg.block_n)
+            out_v[qsel[:, None], np.arange(k_eff)[None, :]] = vals
+            out_i[qsel[:, None], np.arange(k_eff)[None, :]] = ids[idx]
             rows_scanned += n_real * len(qsel)
         return rows_scanned
 
@@ -1069,14 +1063,10 @@ class IVFIndex:
             k_eff = min(k, n_real)
             kprime = self._kprime(k_eff, n_real, rerank, rerank_mult)
             vals, idx = pq_adc_topk(
-                jnp.asarray(luts[qsel]), jnp.asarray(codes), kprime,
-                block_n=self.cfg.block_n,
-                bias=(None if bias is None else jnp.asarray(bias)),
-                row_bucket=(None if rb is None
-                            else jnp.asarray(rb, jnp.int32)),
-                cscores=(None if cterm is None
-                         else jnp.asarray(cterm[qsel])))
-            idx = np.asarray(idx).astype(np.int64)           # [Qg, k']
+                luts[qsel], codes, kprime, block_n=self.cfg.block_n,
+                bias=bias, row_bucket=rb,
+                cscores=(None if cterm is None else cterm[qsel]))
+            idx = idx.astype(np.int64)                       # [Qg, k']
             if rerank:
                 cand = self._fetch_rows(comp_rows, pend_stack,
                                         idx)                 # [Qg, k', d]
@@ -1090,7 +1080,7 @@ class IVFIndex:
                     ids[idx[rows, order]]
             else:
                 out_v[qsel[:, None], np.arange(k_eff)[None, :]] = \
-                    np.asarray(vals)[:, :k_eff]
+                    vals[:, :k_eff]
                 out_i[qsel[:, None], np.arange(k_eff)[None, :]] = \
                     ids[idx[:, :k_eff]]
             rows_scanned += n_real * len(qsel)
@@ -1123,14 +1113,11 @@ class IVFIndex:
         luts = self._pq_luts(queries)                        # [Q, m, ksub]
         residual = cterm is not None
         vals, idx = pq_adc_topk(
-            jnp.asarray(luts), jnp.asarray(self.codes), kprime,
-            block_n=self.cfg.block_n,
-            bias=(jnp.asarray(self.code_bias) if residual else None),
-            row_bucket=jnp.asarray(self.bucket_of, jnp.int32),
-            cscores=(jnp.asarray(cterm) if residual else None),
-            probe_mask=jnp.asarray(pm))
-        vals = np.asarray(vals)
-        idx = np.asarray(idx).astype(np.int64)               # [Q, k']; -1 pad
+            luts, self.codes, kprime, block_n=self.cfg.block_n,
+            bias=(self.code_bias if residual else None),
+            row_bucket=self.bucket_of,
+            cscores=(cterm if residual else None), probe_mask=pm)
+        idx = idx.astype(np.int64)                           # [Q, k']; -1 pad
         valid = idx >= 0
         safe = np.where(valid, idx, 0)
         rows = np.arange(qn)[:, None]

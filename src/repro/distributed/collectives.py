@@ -14,7 +14,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -43,12 +42,8 @@ def sharded_topk(mesh: Mesh, axis: str, q: jnp.ndarray, corpus: jnp.ndarray,
 def _shard_map(f, mesh, in_specs, out_specs):
     """shard_map with replication checking off (top_k after all_gather is
     replicated, but the checker cannot infer that statically)."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def partial_softmax_combine(mesh: Mesh, axis: str, scores: jnp.ndarray,

@@ -1,4 +1,4 @@
-"""Dispatching wrapper: Pallas ADC kernel on TPU, jnp oracle elsewhere.
+"""Dispatching wrapper: Pallas ADC kernel on TPU, jitted XLA twin elsewhere.
 
 The kernel path is exact for any k (per-tile top-k >= global contribution of
 that tile), so parity with ref.py is bitwise on candidate ids (the LUT sums
@@ -20,20 +20,18 @@ tail -- the same contract the shard merge already truncates.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.kernels.dispatch import (on_tpu, pad_rows, padded_queries,
+                                    padded_rows, use_pallas)
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
                                            pq_adc_topk_pallas)
 
-_KERNEL_MAX_K = 64
 _NEG_THRESH = -1.5e38   # kernel NEG mask values live below this
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -79,15 +77,12 @@ def _pq_topk_xla_ext(luts: jnp.ndarray, codes: jnp.ndarray,
     return vals, idx.astype(jnp.int32)
 
 
-def pq_adc_topk(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
-                block_n: int = 512, n_valid: int = -1,
-                force_pallas: bool = False,
-                bias: Optional[jnp.ndarray] = None,
-                row_bucket: Optional[jnp.ndarray] = None,
-                cscores: Optional[jnp.ndarray] = None,
-                probe_mask: Optional[jnp.ndarray] = None
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """[Q, M, K] x [N, M] -> (vals [Q, k'], ids [Q, k']), k' = min(k, n_valid).
+def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
+                force_pallas: bool = False, bias=None, row_bucket=None,
+                cscores=None, probe_mask=None
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """[Q, M, K] x [N, M] -> (vals [Q, k'], ids [Q, k']), k' = min(k, n_valid),
+    as host arrays (inputs are padded on the host: pass host arrays).
 
     Rows at positions >= ``n_valid`` (default: all of ``codes``) are treated
     as padding and excluded from the result; returned indices are always
@@ -101,49 +96,46 @@ def pq_adc_topk(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
         n_valid = n
     k = min(k, n_valid)
     if k <= 0:
-        return (jnp.zeros((qn, 0), jnp.float32),
-                jnp.zeros((qn, 0), jnp.int32))
+        return (np.zeros((qn, 0), np.float32),
+                np.zeros((qn, 0), np.int32))
     ext = any(a is not None for a in (bias, row_bucket, cscores, probe_mask))
     if (cscores is not None or probe_mask is not None) and row_bucket is None:
         raise ValueError("cscores/probe_mask require row_bucket")
-    use_kernel = (force_pallas or _on_tpu()) and k <= _KERNEL_MAX_K
+    use_kernel = use_pallas("pq_scan_ext" if ext else "pq_scan", k,
+                            force_pallas)
+    rows, qrows = padded_rows(n, block_n), padded_queries(qn)
+    luts = pad_rows(luts, qrows, np.float32)
+    codes = pad_rows(codes, rows)
     if not ext:
         if use_kernel:
-            pad = (-n) % block_n
-            if pad:
-                codes = jnp.pad(codes, ((0, pad), (0, 0)))
-            return pq_adc_topk_pallas(luts, codes, k, block_n=block_n,
+            v, i = pq_adc_topk_pallas(luts, codes, k, block_n=block_n,
                                       n_valid=n_valid,
-                                      interpret=not _on_tpu())
-        return _pq_topk_xla(luts, codes, jnp.int32(n_valid), k)
+                                      interpret=not on_tpu())
+        else:
+            v, i = _pq_topk_xla(luts, codes, np.int32(n_valid), k)
+        return np.asarray(v)[:qn], np.asarray(i)[:qn]
 
     masked = probe_mask is not None
     mb = (cscores.shape[1] if cscores is not None
           else probe_mask.shape[1] if probe_mask is not None else 1)
-    bias = (jnp.zeros(n, jnp.float32) if bias is None
-            else jnp.asarray(bias, jnp.float32))
-    rb = (jnp.zeros(n, jnp.int32) if row_bucket is None
-          else jnp.asarray(row_bucket, jnp.int32))
-    cs = (jnp.zeros((qn, mb), jnp.float32) if cscores is None
-          else jnp.asarray(cscores, jnp.float32))
-    pm = (jnp.ones((qn, mb), jnp.float32) if probe_mask is None
-          else jnp.asarray(probe_mask).astype(jnp.float32))
+    bias = pad_rows(np.zeros(n) if bias is None else bias, rows, np.float32)
+    rb = pad_rows(np.zeros(n) if row_bucket is None else row_bucket, rows,
+                  np.int32)
+    cs = pad_rows(np.zeros((qn, mb)) if cscores is None else cscores, qrows,
+                  np.float32)
+    pm = pad_rows(np.ones((qn, mb)) if probe_mask is None else probe_mask,
+                  qrows, np.float32)
     if use_kernel:
-        pad = (-n) % block_n
-        if pad:
-            codes = jnp.pad(codes, ((0, pad), (0, 0)))
-            bias = jnp.pad(bias, (0, pad))
-            rb = jnp.pad(rb, (0, pad))
         v, i = pq_adc_topk_ext_pallas(luts, codes, bias, rb, cs, pm, k,
                                       block_n=block_n, n_valid=n_valid,
-                                      interpret=not _on_tpu())
-        if masked:
-            # in-kernel NEG masking stands in for -inf: restore it and pin
-            # the id payload of empty positions to -1 (the merge contract)
-            v = jnp.where(v <= _NEG_THRESH, -jnp.inf, v)
+                                      interpret=not on_tpu())
     else:
-        v, i = _pq_topk_xla_ext(luts, codes, jnp.int32(n_valid), bias, rb,
+        v, i = _pq_topk_xla_ext(luts, codes, np.int32(n_valid), bias, rb,
                                 cs, pm, k, masked)
+    v, i = np.asarray(v)[:qn], np.asarray(i)[:qn]
     if masked:
-        i = jnp.where(jnp.isfinite(v), i, -1)
+        # the kernel's in-kernel NEG mask stands in for -inf: restore it and
+        # pin the id payload of empty positions to -1 (the merge contract)
+        v = np.where(v <= _NEG_THRESH, -np.inf, v).astype(np.float32)
+        i = np.where(np.isfinite(v), i, -1).astype(np.int32)
     return v, i

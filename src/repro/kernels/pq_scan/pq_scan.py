@@ -33,127 +33,96 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-NEG = -3.0e38
-
-
-def _topl_sweep(s, base, cols, topl, vals_ref, idx_ref):
-    """Tile-local top-L via repeated max-extract (vectorized, L small)."""
-    for l in range(topl):
-        mx = jnp.max(s, axis=-1)                                  # [Q]
-        a = jnp.argmax(s, axis=-1).astype(jnp.int32)              # [Q]
-        vals_ref[:, l] = mx
-        idx_ref[:, l] = a + base
-        s = jnp.where(cols == a[:, None], NEG, s)
+from repro.kernels.sweep import (NEG, merge_tiles, n_valid_operand,
+                                 tile_outputs, tile_topl)
 
 
-def _pq_kernel(luts_ref, codes_ref, vals_ref, idx_ref, *, topl: int,
-               block_n: int, ksub: int, n_valid: int, n_total: int):
-    luts = luts_ref[...]                                  # [Q, M*K] f32
-    codes = codes_ref[...].astype(jnp.int32)              # [BN, M]
-    bn, m = codes.shape
-    # one-hot the codes: onehot[n, j*K + c] = (codes[n, j] == c).  An iota
-    # compare keeps everything dense/vectorized -- the TPU has no cheap
-    # per-lane gather, but a [Q, M*K] x [M*K, BN] contraction is one MXU pass.
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, m, ksub), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32)
-    onehot = onehot.reshape(bn, m * ksub)
-    s = jax.lax.dot_general(luts, onehot, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)   # [Q, BN]
-    base = pl.program_id(0) * block_n
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if n_valid < n_total:
-        # rows past n_valid are padding (code table padded up to a block_n
-        # multiple by the dispatcher): mask them out of every sweep
-        s = jnp.where(cols + base >= n_valid, NEG, s)
-    _topl_sweep(s, base, cols, topl, vals_ref, idx_ref)
-
-
-def _pq_kernel_ext(luts_ref, codes_ref, bias_ref, rb_ref, cs_ref, pm_ref,
-                   vals_ref, idx_ref, *, topl: int, block_n: int, ksub: int,
-                   mb: int, n_valid: int, n_total: int):
-    luts = luts_ref[...]                                  # [Q, M*K] f32
+def _adc_tile(luts_ref, codes_ref, ksub: int):
+    """[Q, BN] ADC scores of one code tile: one-hot the codes,
+    onehot[n, j*K + c] = (codes[n, j] == c), and contract against the
+    flattened LUTs.  An iota compare keeps everything dense/vectorized --
+    the TPU has no cheap per-lane gather, but a [Q, M*K] x [M*K, BN]
+    contraction is one MXU pass."""
     codes = codes_ref[...].astype(jnp.int32)              # [BN, M]
     bn, m = codes.shape
     iota = jax.lax.broadcasted_iota(jnp.int32, (bn, m, ksub), 2)
     onehot = (codes[:, :, None] == iota).astype(jnp.float32)
     onehot = onehot.reshape(bn, m * ksub)
-    s = jax.lax.dot_general(luts, onehot, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)   # [Q, BN]
+    return jax.lax.dot_general(luts_ref[...], onehot,
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _pq_kernel(nv_ref, luts_ref, codes_ref, vals_ref, idx_ref, *, topl: int,
+               block_n: int, ksub: int):
+    s = _adc_tile(luts_ref, codes_ref, ksub)                      # [Q, BN]
+    tile_topl(s, nv_ref, block_n, topl, vals_ref, idx_ref)
+
+
+def _pq_kernel_ext(nv_ref, luts_ref, codes_ref, bias_ref, rb_ref, cs_ref,
+                   pm_ref, vals_ref, idx_ref, *, topl: int, block_n: int,
+                   ksub: int, mb: int):
+    s = _adc_tile(luts_ref, codes_ref, ksub)                      # [Q, BN]
     # bucket terms: one-hot the per-row bucket id and contract the per-query
     # centroid scores / probe mask against it -- two more MXU passes instead
     # of a per-lane gather
-    rb = rb_ref[...].astype(jnp.int32)                    # [BN]
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (bn, mb), 1)
-    onehot_b = (rb[:, None] == iota_b).astype(jnp.float32)        # [BN, MB]
+    rb = rb_ref[...]                                      # [1, BN] int32
+    iota_b = jax.lax.broadcasted_iota(jnp.int32, (mb, rb.shape[1]), 0)
+    onehot_b = (iota_b == rb).astype(jnp.float32)                 # [MB, BN]
     cterm = jax.lax.dot_general(cs_ref[...], onehot_b,
-                                (((1,), (1,)), ((), ())),
+                                (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
     mterm = jax.lax.dot_general(pm_ref[...], onehot_b,
-                                (((1,), (1,)), ((), ())),
+                                (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    s = s + cterm + bias_ref[...][None, :]
+    s = s + cterm + bias_ref[...]                         # bias tile [1, BN]
     s = jnp.where(mterm > 0.5, s, NEG)
-    base = pl.program_id(0) * block_n
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if n_valid < n_total:
-        s = jnp.where(cols + base >= n_valid, NEG, s)
-    _topl_sweep(s, base, cols, topl, vals_ref, idx_ref)
+    tile_topl(s, nv_ref, block_n, topl, vals_ref, idx_ref)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "n_valid", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
 def pq_adc_topk_pallas(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
-                       block_n: int = 512, n_valid: int = -1,
+                       block_n: int = 512, n_valid=-1,
                        interpret: bool = True
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """[Q, M, K] x [N, M] -> (vals [Q, k], ids [Q, k]); N % block_n == 0.
 
-    ``n_valid`` (< N) marks the tail rows as padding: their scores are pinned
-    to ``NEG`` inside the kernel, so the dispatcher can pad any code table up
-    to a block_n multiple without padded rows ever reaching the top-k."""
+    ``n_valid`` (< N; traced) marks the tail rows as padding: their scores
+    are pinned to ``NEG`` inside the kernel, so the dispatcher can pad any
+    code table up to a block_n multiple without padded rows ever reaching
+    the top-k.  The caller keeps k <= n_valid."""
     qn, m, ksub = luts.shape
     n = codes.shape[0]
     assert codes.shape[1] == m, (codes.shape, m)
     assert n % block_n == 0, (n, block_n)
-    if n_valid < 0:
-        n_valid = n
-    assert k <= n_valid, (k, n_valid)
     n_tiles = n // block_n
     luts_flat = luts.astype(jnp.float32).reshape(qn, m * ksub)
-    codes = codes.astype(jnp.int32)
+    out_specs, out_shape = tile_outputs(n_tiles, qn, k)
 
     kernel = functools.partial(_pq_kernel, topl=k, block_n=block_n,
-                               ksub=ksub, n_valid=n_valid, n_total=n)
+                               ksub=ksub)
     vals, idx = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # n_valid
             pl.BlockSpec((qn, m * ksub), lambda i: (0, 0)),  # luts: resident
             pl.BlockSpec((block_n, m), lambda i: (i, 0)),    # code tile
         ],
-        out_specs=[
-            pl.BlockSpec((qn, k), lambda i: (0, i)),         # per-tile topL
-            pl.BlockSpec((qn, k), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, n_tiles * k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, n_tiles * k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(luts_flat, codes)
-
-    # epilogue: merge per-tile partials (tiny)
-    mv, mi = jax.lax.top_k(vals, k)
-    return mv, jnp.take_along_axis(idx, mi, axis=1)
+    )(n_valid_operand(n_valid, n), luts_flat, codes.astype(jnp.int32))
+    return merge_tiles(vals, idx, k)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "block_n", "n_valid", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
 def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
                            bias: jnp.ndarray, row_bucket: jnp.ndarray,
                            cscores: jnp.ndarray, probe_mask: jnp.ndarray,
-                           k: int, block_n: int = 512, n_valid: int = -1,
+                           k: int, block_n: int = 512, n_valid=-1,
                            interpret: bool = True
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Extended ADC scan: LUT sum + bias[n] + cscores[q, row_bucket[n]],
@@ -167,39 +136,32 @@ def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
     assert n % block_n == 0, (n, block_n)
     assert probe_mask.shape == cscores.shape, (probe_mask.shape,
                                                cscores.shape)
-    if n_valid < 0:
-        n_valid = n
-    assert k <= n_valid, (k, n_valid)
     n_tiles = n // block_n
     luts_flat = luts.astype(jnp.float32).reshape(qn, m * ksub)
-    codes = codes.astype(jnp.int32)
+    out_specs, out_shape = tile_outputs(n_tiles, qn, k)
 
     kernel = functools.partial(_pq_kernel_ext, topl=k, block_n=block_n,
-                               ksub=ksub, mb=mb, n_valid=n_valid, n_total=n)
+                               ksub=ksub, mb=mb)
+    # bias and row_bucket ride as [1, N] rows: 1-D tiles of block_n lanes
+    # have no TPU layout XLA and Mosaic agree on, and an [N, 1] column
+    # would pad every row out to 128 lanes in HBM
     vals, idx = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),           # n_valid
             pl.BlockSpec((qn, m * ksub), lambda i: (0, 0)),  # luts: resident
             pl.BlockSpec((block_n, m), lambda i: (i, 0)),    # code tile
-            pl.BlockSpec((block_n,), lambda i: (i,)),        # bias tile
-            pl.BlockSpec((block_n,), lambda i: (i,)),        # bucket tile
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),    # bias tile
+            pl.BlockSpec((1, block_n), lambda i: (0, i)),    # bucket tile
             pl.BlockSpec((qn, mb), lambda i: (0, 0)),        # cscores: res
             pl.BlockSpec((qn, mb), lambda i: (0, 0)),        # mask: res
         ],
-        out_specs=[
-            pl.BlockSpec((qn, k), lambda i: (0, i)),         # per-tile topL
-            pl.BlockSpec((qn, k), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, n_tiles * k), jnp.float32),
-            jax.ShapeDtypeStruct((qn, n_tiles * k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(luts_flat, codes, bias.astype(jnp.float32),
-      row_bucket.astype(jnp.int32), cscores.astype(jnp.float32),
+    )(n_valid_operand(n_valid, n), luts_flat, codes.astype(jnp.int32),
+      bias.astype(jnp.float32)[None, :],
+      row_bucket.astype(jnp.int32)[None, :], cscores.astype(jnp.float32),
       probe_mask.astype(jnp.float32))
-
-    # epilogue: merge per-tile partials (tiny)
-    mv, mi = jax.lax.top_k(vals, k)
-    return mv, jnp.take_along_axis(idx, mi, axis=1)
+    return merge_tiles(vals, idx, k)

@@ -22,13 +22,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import on_tpu, use_pallas
 from repro.kernels.topk_merge.topk_merge import merge_topk_pallas
-
-_KERNEL_MAX_K = 64
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -68,14 +63,13 @@ def merge_topk_dev(vals: jnp.ndarray, ids: jnp.ndarray, k: int,
     flat_v = jnp.transpose(jnp.asarray(vals, jnp.float32),
                            (1, 0, 2)).reshape(qn, c)
     flat_i = jnp.transpose(jnp.asarray(ids), (1, 0, 2)).reshape(qn, c)
-    use_kernel = (force_pallas or _on_tpu()) and k <= _KERNEL_MAX_K
-    if use_kernel:
+    if use_pallas("topk_merge", k, force_pallas):
         pad = (-qn) % block_q
         if pad:
             flat_v = jnp.pad(flat_v, ((0, pad), (0, 0)))
             flat_i = jnp.pad(flat_i, ((0, pad), (0, 0)))
         mv, mi = merge_topk_pallas(flat_v, flat_i, k, block_q=block_q,
                                    n_valid=n_valid,
-                                   interpret=not _on_tpu())
+                                   interpret=not on_tpu())
         return mv[:qn], mi[:qn]
     return _merge_topk_xla(flat_v, flat_i, jnp.int32(n_valid), k)

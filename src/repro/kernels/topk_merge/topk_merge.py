@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-NEG = -3.0e38     # in-sweep mask: strictly below every selectable score
+from repro.kernels.sweep import NEG, topl_sweep  # NEG: in-sweep mask
+
 CLAMP = -1.0e38   # input floor: -inf padding clamps here, above NEG
 
 
@@ -44,12 +45,7 @@ def _merge_kernel(v_ref, vals_ref, pos_ref, *, topl: int, n_valid: int,
         # k <= n_valid, so the sweep never runs out of CLAMP-or-better
         # columns and NEG-masked ones are never selected
         s = jnp.where(cols >= n_valid, NEG, s)
-    for l in range(topl):
-        m = jnp.max(s, axis=-1)                                # [BQ]
-        a = jnp.argmax(s, axis=-1).astype(jnp.int32)           # [BQ]
-        vals_ref[:, l] = m
-        pos_ref[:, l] = a
-        s = jnp.where(cols == a[:, None], NEG, s)
+    vals_ref[...], pos_ref[...] = topl_sweep(s, cols, topl)
 
 
 @functools.partial(jax.jit,

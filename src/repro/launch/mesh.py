@@ -10,8 +10,6 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-import repro.jax_compat  # noqa: F401  (installs AxisType/set_mesh on old jax)
 from jax.sharding import AxisType
 
 

@@ -29,6 +29,7 @@ from repro.configs.pandadb import PandaDBConfig, ServingConfig, VectorIndexConfi
 from repro.core import PandaDB
 from repro.core.aipm import feature_hash_extractor, label_extractor
 from repro.data.synthetic_graph import SNBConfig, build_snb
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import prometheus_dump
 from repro.serving.engine import QueryServer
 
@@ -135,6 +136,7 @@ def main() -> None:
                     help="print a Prometheus-style text dump of every live "
                          "metrics registry after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.chaos and args.replicas < 2:
         ap.error("--chaos needs --replicas >= 2 (a lone replica cannot "

@@ -1,8 +1,6 @@
 import numpy as np
 import pytest
 
-import repro.jax_compat  # noqa: F401  (AxisType/set_mesh shims for old jax)
-
 # NOTE: no XLA_FLAGS here on purpose -- smoke tests must see the single real
 # CPU device; multi-device tests spawn subprocesses with their own flags.
 

@@ -46,7 +46,6 @@ _SUBPROCESS_SNIPPET = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import numpy as np
-    import repro.jax_compat  # AxisType/set_mesh shims for old jax
     import jax, jax.numpy as jnp
     from jax.sharding import AxisType
     from repro.core.vector_index import scan_topk
@@ -98,7 +97,6 @@ def test_reduced_model_lowering_on_16dev():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
         import json
-        import repro.jax_compat  # AxisType/set_mesh shims for old jax
         import jax, jax.numpy as jnp
         from jax.sharding import AxisType
         from repro.configs.base import TransformerConfig

@@ -149,6 +149,25 @@ def test_var_var_pushdown_matches_extraction_path():
     assert pushed == baseline
 
 
+def test_var_var_pushdown_queries_from_the_anchor_side():
+    """A one-row anchor joined against a whole label (`n.name = ... AND
+    n.photo->face ~: m.photo->face`) searches the index once, from the
+    anchor's photo, across every chunk -- same rows as extraction."""
+    text = ("MATCH (n:Person), (m:Person) WHERE n.name = 'p_2' "
+            "AND n.photo->face ~: m.photo->face RETURN m.name")
+    baseline = {r["m.name"] for r in _face_db().query(text)}
+    assert baseline == {"p_2", "p_3"}
+    db = _face_db()
+    index = db.build_index("face", "photo")
+    calls = []
+    search = index.search_many
+    index.search_many = lambda q, *a, **kw: (calls.append(len(q)),
+                                             search(q, *a, **kw))[1]
+    rows = db.session(batch_rows=8).run(text).fetchall()   # several chunks
+    assert {r["m.name"] for r in rows} == baseline
+    assert calls == [1]
+
+
 def test_self_similarity_pushdown_short_circuits():
     """`x ~: x` with an index: rows with a blob pass without any search."""
     db = _face_db(20)

@@ -464,3 +464,42 @@ def test_pq_ext_requires_row_bucket():
     with pytest.raises(ValueError, match="row_bucket"):
         pq_adc_topk(jnp.asarray(luts), jnp.asarray(codes), 5,
                     cscores=jnp.asarray(cs))
+
+
+# -- dispatch counters -------------------------------------------------------
+
+def _dispatch_call(kernel, k):
+    if kernel == "ivf_scan":
+        ivf_scan_topk(RNG.standard_normal((2, 32)).astype(np.float32),
+                      RNG.standard_normal((600, 32)).astype(np.float32), k,
+                      force_pallas=True)
+    elif kernel == "topk_merge":
+        vals, ids = _merge_inputs(2, 3, 80, seed=k)
+        merge_topk_dev(jnp.asarray(vals), jnp.asarray(ids), k,
+                       force_pallas=True)
+    else:
+        luts, codes, bias, rb, cs, _ = _ext_inputs(2, 600, 8, 64, 4, seed=k)
+        ext = (dict(bias=bias, row_bucket=rb, cscores=cs)
+               if kernel == "pq_scan_ext" else {})
+        pq_adc_topk(luts, codes, k, force_pallas=True, **ext)
+
+
+@pytest.mark.parametrize("kernel", ["ivf_scan", "pq_scan", "pq_scan_ext",
+                                    "topk_merge"])
+@pytest.mark.parametrize("k,impl", [(8, "pallas"), (80, "xla")])
+def test_dispatch_counts_each_implementation(kernel, k, impl):
+    """force_pallas takes the kernel up to k=64 and the XLA twin above it;
+    each call counts once under the implementation it took, and a Pallas
+    call off the chip also counts as interpreted."""
+    from repro.kernels.dispatch import KERNEL_MAX_K, dispatch_counts
+    assert (k <= KERNEL_MAX_K) == (impl == "pallas")
+    before = dispatch_counts()
+    _dispatch_call(kernel, k)
+    after = dispatch_counts()
+    delta = {key: after[key] - before.get(key, 0) for key in after
+             if key.startswith(f"{kernel}:")
+             and after[key] != before.get(key, 0)}
+    want = {f"{kernel}:{impl}": 1}
+    if impl == "pallas":
+        want[f"{kernel}:interpret"] = 1
+    assert delta == want
