@@ -137,8 +137,7 @@ def _copy_piece(piece: IVFIndex) -> IVFIndex:
     piece.compact()
     return dataclasses.replace(piece, _pend_vecs={}, _pend_ids={},
                                _pend_codes={}, _pend_bias={},
-                               pending_count=0,
-                               scan_rows=0, scan_time=0.0)
+                               pending_count=0, scan_rows=0)
 
 
 def _apply_op(db: PandaDB, op: str, args: tuple, kw: Dict[str, Any]) -> Any:

@@ -642,7 +642,10 @@ class _ResilientIndex:
         return v, i, piece.scan_rows - rows0
 
     def search_many(self, queries, k, nprobe=None, stats=None, mode="auto",
-                    rerank=True, rerank_mult=None):
+                    rerank=True, rerank_mult=None, trace=None):
+        """Hedged search of the shard.  ``trace`` is the scatter's, taken
+        for the shard duck type and not passed to the replicas: a hedge
+        race runs the phases twice, on pool threads of its own."""
         cdb, s = self.cdb, self.shard
         rs = cdb.replica_sets[s]
         deadline = self.deadline

@@ -200,7 +200,6 @@ class ObsConfig:
 
     trace: bool = False             # per-query span trees; OFF by default —
     #                                 disabled tracing must cost near-zero
-    #                                 (gated by bench_obs_overhead.py)
     trace_keep_last: bool = True    # tracer keeps the most recent Trace for
     #                                 inspection (tracer.last)
     slow_query_ms: float = 0.0      # serving engine writes a JSON line for

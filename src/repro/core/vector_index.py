@@ -44,9 +44,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.pandadb import VectorIndexConfig
+from repro.kernels.dispatch import count_h2d
 from repro.kernels.ivf_scan.ops import ivf_scan_topk
 from repro.kernels.pq_scan.ops import pq_adc_topk
 from repro.kernels.topk_merge.ops import merge_topk_dev
+from repro.obs.trace import maybe_span
 
 
 # ---------------------------------------------------------------------------
@@ -192,11 +194,12 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     as fabricated candidates.  ``partial_topk`` is noted on the deadline;
     if NO shard answers in time, :class:`DeadlineExceeded` is raised.
 
-    ``trace`` (a :class:`repro.obs.Trace`, optional) records one
+    ``trace`` (a :class:`repro.obs.Trace`, optional) records one live
     ``knn.shard_scan`` span per shard (attributed with rows scanned and
-    re-rank mode, correct even off pool threads), a ``knn.merge`` span for
-    the device-side reduce, and a ``degradation`` event when the partial
-    top-k ladder step fires."""
+    re-rank mode, correct even off pool threads; the shard's
+    ``search_many`` nests its phases under it), a ``knn.merge`` span for
+    the device-side reduce with the bytes it uploads (``h2d_bytes``), and
+    a ``degradation`` event when the partial top-k ladder step fires."""
     queries = np.asarray(queries, np.float32)
     qn = queries.shape[0]
     out_v = np.full((qn, k), -np.inf, np.float32)
@@ -217,14 +220,16 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     def scan_one(s: int):
         t0 = time.perf_counter()
         rows0 = shards[s].scan_rows
-        v, i = shards[s].search_many(queries, k, nprobe, stats=per_stats[s],
-                                     mode=mode, rerank=rerank,
-                                     rerank_mult=rm)
+        with maybe_span(trace, "knn.shard_scan", parent=t_parent,
+                        shard=s) as sp:
+            v, i = shards[s].search_many(queries, k, nprobe,
+                                         stats=per_stats[s], mode=mode,
+                                         rerank=rerank, rerank_mult=rm,
+                                         trace=trace)
+            scanned = shards[s].scan_rows - rows0
+            if sp is not None:
+                sp.set(rows=int(scanned), rerank=rerank)
         dt = time.perf_counter() - t0
-        scanned = shards[s].scan_rows - rows0
-        if trace is not None:
-            trace.add_timed("knn.shard_scan", dt, parent=t_parent, shard=s,
-                            rows=int(scanned), rerank=rerank)
         if record is not None:
             record(s, dt, scanned)
         return v, i
@@ -272,11 +277,13 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     else:
         parts = [scan_one(s) for s in range(len(shards))]
     t_merge = time.perf_counter()
+    up = count_h2d("topk_merge", None, *(a for p in parts for a in p))
     v, i = merge_topk_dev(jnp.stack([jnp.asarray(p[0]) for p in parts]),
                           jnp.stack([jnp.asarray(p[1]) for p in parts]), k)
     if trace is not None:
         trace.add_timed("knn.merge", time.perf_counter() - t_merge,
-                        parent=t_parent, shards=len(parts), k=k)
+                        parent=t_parent, shards=len(parts), k=k,
+                        h2d_bytes=up)
     total = sum(sh.n_total for sh in shards)
     kk = min(k, total, v.shape[1])
     v = np.asarray(v)[:, :kk]
@@ -498,9 +505,8 @@ class IVFIndex:
     _pend_bias: Dict[int, List[float]] = dataclasses.field(
         default_factory=dict, repr=False)
     pending_count: int = 0
-    # observed scan throughput (feeds the cost model's kNN term)
+    # rows scanned so far (the coordinator's per-shard throughput feed)
     scan_rows: int = 0
-    scan_time: float = 0.0
 
     @property
     def n_total(self) -> int:
@@ -718,7 +724,7 @@ class IVFIndex:
     def search_many(self, queries: np.ndarray, k: int,
                     nprobe: Optional[int] = None, stats=None,
                     mode: str = "auto", rerank: bool = True,
-                    rerank_mult: Optional[int] = None
+                    rerank_mult: Optional[int] = None, trace=None
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched two-phase kNN over the whole query set.
 
@@ -769,7 +775,17 @@ class IVFIndex:
         candidate (probe set smaller than ``k``) hold val=-inf / id=-1.
         ``stats``, if given, receives the observed scan throughput via
         ``record_knn_scan`` / ``record_pq_scan`` / ``record_fused_scan``
-        (cost-model feedback)."""
+        (cost-model feedback).
+
+        ``trace`` (a :class:`repro.obs.Trace`, optional) gets a live span
+        per phase, nested under the caller's current span: ``knn.probe``
+        (centroid scoring, top-``nprobe``, the copy back to the host, the
+        residual centroid term and the signature sort), ``knn.luts``,
+        ``knn.gather`` (bucket gathers of the staged paths), one
+        ``knn.scan`` per kernel call (``kernel``, ``impl``), ``knn.rerank``
+        (row fetch, exact scores, ordering), and ``knn.host_scan`` for the
+        single-query host path.  Spans that hand host arrays to a device
+        program carry their bytes as ``h2d_bytes``."""
         if mode not in ("auto", "adc", "float", "fused"):
             raise ValueError(f"unknown scan mode {mode!r}; "
                              f"expected auto | adc | float | fused")
@@ -784,38 +800,43 @@ class IVFIndex:
         kind = self._pick_scan(mode, stats, qn, k)
         if qn == 1:
             t0 = time.perf_counter()
-            rows_scanned = self._search_one(queries, k, nprobe, out_v, out_i,
-                                            kind == "adc", rerank,
-                                            rerank_mult)
+            with maybe_span(trace, "knn.host_scan"):
+                rows_scanned = self._search_one(queries, k, nprobe, out_v,
+                                                out_i, kind == "adc", rerank,
+                                                rerank_mult)
             self._note_scan(stats, time.perf_counter() - t0, rows_scanned,
                             kind)
             return out_v, out_i
-        q = jnp.asarray(queries)
-        cscores = pairwise_scores(q, jnp.asarray(self.centroids),
-                                  self.cfg.metric)
-        _, probe = jax.lax.top_k(cscores, nprobe)          # [Q, nprobe]
-        cterm = None
-        if self.cfg.pq_residual and kind in ("adc", "fused"):
-            cterm = self._cterm_np(queries, np.asarray(cscores))
-        # probe *signature* = the bucket set; sort so order never splits groups
-        probe = np.sort(np.asarray(probe), axis=1)
+        with maybe_span(trace, "knn.probe") as sp:
+            count_h2d("probe", sp, queries, self.centroids)
+            cscores = pairwise_scores(jnp.asarray(queries),
+                                      jnp.asarray(self.centroids),
+                                      self.cfg.metric)
+            _, probe = jax.lax.top_k(cscores, nprobe)      # [Q, nprobe]
+            cterm = None
+            if self.cfg.pq_residual and kind in ("adc", "fused"):
+                cterm = self._cterm_np(queries, np.asarray(cscores))
+            # probe *signature* = the bucket set; sort so order never
+            # splits groups
+            probe = np.sort(np.asarray(probe), axis=1)
         t0 = time.perf_counter()
         if kind == "fused":
             rows_scanned = self._scan_fused(queries, cterm, probe, k,
                                             out_v, out_i, rerank,
-                                            rerank_mult)
+                                            rerank_mult, trace)
         else:
             sigs, inverse = np.unique(probe, axis=0, return_inverse=True)
             if kind == "adc":
                 rows_scanned = self._scan_groups_pq(queries, sigs, inverse,
                                                     k, out_v, out_i, rerank,
-                                                    cterm, rerank_mult)
+                                                    cterm, rerank_mult,
+                                                    trace)
             elif sigs.shape[0] > 1 and sigs.shape[0] * nprobe >= m:
                 rows_scanned = self._scan_dense(queries, probe, k,
-                                                out_v, out_i)
+                                                out_v, out_i, trace)
             else:
                 rows_scanned = self._scan_groups(queries, sigs, inverse, k,
-                                                 out_v, out_i)
+                                                 out_v, out_i, trace)
         self._note_scan(stats, time.perf_counter() - t0, rows_scanned,
                         kind)
         return out_v, out_i
@@ -839,7 +860,6 @@ class IVFIndex:
     def _note_scan(self, stats, dt: float, rows_scanned: int,
                    kind: str) -> None:
         self.scan_rows += rows_scanned
-        self.scan_time += dt
         if stats is not None and rows_scanned:
             if kind == "fused":
                 stats.record_fused_scan(dt, rows_scanned)
@@ -1022,19 +1042,22 @@ class IVFIndex:
 
     def _scan_groups(self, queries: np.ndarray, sigs: np.ndarray,
                      inverse: np.ndarray, k: int,
-                     out_v: np.ndarray, out_i: np.ndarray) -> int:
+                     out_v: np.ndarray, out_i: np.ndarray,
+                     trace=None) -> int:
         """One fused gathered scan per distinct probe signature."""
         rows_scanned = 0
         for g in range(sigs.shape[0]):
             qsel = np.nonzero(inverse == g)[0]
-            corpus, ids = self._gather_buckets(sigs[g])
+            with maybe_span(trace, "knn.gather"):
+                corpus, ids = self._gather_buckets(sigs[g])
             n_real = corpus.shape[0]
             if n_real == 0:
                 continue
             k_eff = min(k, n_real)
-            vals, idx = ivf_scan_topk(queries[qsel], corpus, k_eff,
-                                      metric=self.cfg.metric,
-                                      block_n=self.cfg.block_n)
+            with maybe_span(trace, "knn.scan") as sp:
+                vals, idx = ivf_scan_topk(queries[qsel], corpus, k_eff,
+                                          metric=self.cfg.metric,
+                                          block_n=self.cfg.block_n, span=sp)
             out_v[qsel[:, None], np.arange(k_eff)[None, :]] = vals
             out_i[qsel[:, None], np.arange(k_eff)[None, :]] = ids[idx]
             rows_scanned += n_real * len(qsel)
@@ -1044,35 +1067,42 @@ class IVFIndex:
                         inverse: np.ndarray, k: int,
                         out_v: np.ndarray, out_i: np.ndarray,
                         rerank: bool, cterm: Optional[np.ndarray] = None,
-                        rerank_mult: Optional[int] = None) -> int:
+                        rerank_mult: Optional[int] = None,
+                        trace=None) -> int:
         """PQ two-stage scan, one dispatch per distinct probe signature:
         ADC top-k' over the gathered uint8 codes (``pq_adc_topk``: Pallas
         kernel on TPU, fused XLA gathers elsewhere), then exact re-rank of
         the k' candidates against the original float rows.  ``cterm``
         ([Q, m], residual mode) carries each query's centroid term; the
         per-row bias + bucket id ride along from :meth:`_gather_codes`."""
-        luts = self._pq_luts(queries)                        # [Q, m, ksub]
+        with maybe_span(trace, "knn.luts"):
+            luts = self._pq_luts(queries)                    # [Q, m, ksub]
         rows_scanned = 0
         for g in range(sigs.shape[0]):
             qsel = np.nonzero(inverse == g)[0]
-            codes, ids, comp_rows, pend_stack, rb, bias = \
-                self._gather_codes(sigs[g])
+            with maybe_span(trace, "knn.gather"):
+                codes, ids, comp_rows, pend_stack, rb, bias = \
+                    self._gather_codes(sigs[g])
             n_real = codes.shape[0]
             if n_real == 0:
                 continue
             k_eff = min(k, n_real)
             kprime = self._kprime(k_eff, n_real, rerank, rerank_mult)
-            vals, idx = pq_adc_topk(
-                luts[qsel], codes, kprime, block_n=self.cfg.block_n,
-                bias=bias, row_bucket=rb,
-                cscores=(None if cterm is None else cterm[qsel]))
+            with maybe_span(trace, "knn.scan") as sp:
+                vals, idx = pq_adc_topk(
+                    luts[qsel], codes, kprime, block_n=self.cfg.block_n,
+                    bias=bias, row_bucket=rb,
+                    cscores=(None if cterm is None else cterm[qsel]),
+                    span=sp)
             idx = idx.astype(np.int64)                       # [Qg, k']
             if rerank:
-                cand = self._fetch_rows(comp_rows, pend_stack,
-                                        idx)                 # [Qg, k', d]
-                exact = _exact_scores_np(queries[qsel], cand,
-                                         self.cfg.metric)    # [Qg, k']
-                order = np.argsort(-exact, axis=1, kind="stable")[:, :k_eff]
+                with maybe_span(trace, "knn.rerank"):
+                    cand = self._fetch_rows(comp_rows, pend_stack,
+                                            idx)             # [Qg, k', d]
+                    exact = _exact_scores_np(queries[qsel], cand,
+                                             self.cfg.metric)  # [Qg, k']
+                    order = np.argsort(-exact, axis=1,
+                                       kind="stable")[:, :k_eff]
                 rows = np.arange(len(qsel))[:, None]
                 out_v[qsel[:, None], np.arange(k_eff)[None, :]] = \
                     exact[rows, order]
@@ -1089,7 +1119,8 @@ class IVFIndex:
     def _scan_fused(self, queries: np.ndarray, cterm: Optional[np.ndarray],
                     probe: np.ndarray, k: int,
                     out_v: np.ndarray, out_i: np.ndarray,
-                    rerank: bool, rerank_mult: Optional[int] = None) -> int:
+                    rerank: bool, rerank_mult: Optional[int] = None,
+                    trace=None) -> int:
         """Fused probe->ADC->top-k': ONE ``pq_adc_topk`` dispatch over the
         whole code table for the entire batch, each query's non-probed
         buckets pinned to -inf in-kernel via ``probe_mask`` -- no signature
@@ -1110,22 +1141,26 @@ class IVFIndex:
         kprime = self._kprime(k_eff, n_real, rerank, rerank_mult)
         pm = np.zeros((qn, m), bool)
         pm[np.arange(qn)[:, None], probe] = True
-        luts = self._pq_luts(queries)                        # [Q, m, ksub]
+        with maybe_span(trace, "knn.luts"):
+            luts = self._pq_luts(queries)                    # [Q, m, ksub]
         residual = cterm is not None
-        vals, idx = pq_adc_topk(
-            luts, self.codes, kprime, block_n=self.cfg.block_n,
-            bias=(self.code_bias if residual else None),
-            row_bucket=self.bucket_of,
-            cscores=(cterm if residual else None), probe_mask=pm)
+        with maybe_span(trace, "knn.scan") as sp:
+            vals, idx = pq_adc_topk(
+                luts, self.codes, kprime, block_n=self.cfg.block_n,
+                bias=(self.code_bias if residual else None),
+                row_bucket=self.bucket_of,
+                cscores=(cterm if residual else None), probe_mask=pm,
+                span=sp)
         idx = idx.astype(np.int64)                           # [Q, k']; -1 pad
         valid = idx >= 0
         safe = np.where(valid, idx, 0)
         rows = np.arange(qn)[:, None]
         if rerank:
-            cand = self.vectors[safe]                        # [Q, k', d]
-            exact = _exact_scores_np(queries, cand, self.cfg.metric)
-            exact = np.where(valid, exact, -np.inf)
-            order = np.argsort(-exact, axis=1, kind="stable")[:, :k_eff]
+            with maybe_span(trace, "knn.rerank"):
+                cand = self.vectors[safe]                    # [Q, k', d]
+                exact = _exact_scores_np(queries, cand, self.cfg.metric)
+                exact = np.where(valid, exact, -np.inf)
+                order = np.argsort(-exact, axis=1, kind="stable")[:, :k_eff]
             v = exact[rows, order]
             gid = self.ids[safe][rows, order]
         else:
@@ -1136,11 +1171,13 @@ class IVFIndex:
         return qn * n_real
 
     def _scan_dense(self, queries: np.ndarray, probe: np.ndarray, k: int,
-                    out_v: np.ndarray, out_i: np.ndarray) -> int:
+                    out_v: np.ndarray, out_i: np.ndarray,
+                    trace=None) -> int:
         """One masked scan of the full table for scattered probe batches."""
         m = self.centroids.shape[0]
         qn = queries.shape[0]
-        corpus, ids, row_bucket = self._full_corpus()
+        with maybe_span(trace, "knn.gather"):
+            corpus, ids, row_bucket = self._full_corpus()
         n_real = corpus.shape[0]
         pad = (-n_real) % self.cfg.block_n
         if pad:
@@ -1152,11 +1189,14 @@ class IVFIndex:
         probe_mask = np.zeros((qn, m + 1), bool)
         probe_mask[np.arange(qn)[:, None], probe] = True
         k_eff = min(k, n_real)
-        vals, idx = masked_scan_topk(
-            jnp.asarray(queries), jnp.asarray(corpus),
-            jnp.asarray(row_bucket), jnp.asarray(probe_mask), k_eff,
-            self.cfg.metric)
-        vals = np.asarray(vals)
+        with maybe_span(trace, "knn.scan") as sp:
+            count_h2d("masked_scan", sp, queries, corpus, row_bucket,
+                      probe_mask, kernel="masked_scan", impl="xla")
+            vals, idx = masked_scan_topk(
+                jnp.asarray(queries), jnp.asarray(corpus),
+                jnp.asarray(row_bucket), jnp.asarray(probe_mask), k_eff,
+                self.cfg.metric)
+            vals = np.asarray(vals)
         gids = ids[np.clip(np.asarray(idx), 0, n_real - 1)]
         out_v[:, :k_eff] = vals
         out_i[:, :k_eff] = np.where(np.isfinite(vals), gids, -1)

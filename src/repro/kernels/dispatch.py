@@ -9,6 +9,11 @@ jitted XLA twin runs instead.  Each decision is counted in ``METRICS`` (the
 a Pallas call that runs in interpret mode also counts ``:interpret``, so a
 run on the chip can show which code executed and that none of it was
 interpreted.
+
+The same registry counts what the kNN path copies to the device,
+``h2d_bytes:<site>`` (:func:`count_h2d`), and the process's backend
+compilations, ``jit_compiles``.  Neither name starts with
+``kernel_dispatch:``, so readers of the dispatch counters never see them.
 """
 from __future__ import annotations
 
@@ -16,12 +21,21 @@ from typing import Dict
 
 import jax
 import numpy as np
+from jax import monitoring
 
 from repro.obs.metrics import MetricsRegistry
 
 KERNEL_MAX_K = 64
 
 METRICS = MetricsRegistry("pandadb")
+
+
+def _on_jax_event(event: str, duration: float, **_kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        METRICS.counter("jit_compiles").inc()
+
+
+monitoring.register_event_duration_secs_listener(_on_jax_event)
 
 
 def on_tpu() -> bool:
@@ -38,6 +52,26 @@ def use_pallas(kernel: str, k: int, force_pallas: bool = False) -> bool:
     if pallas and not tpu:
         METRICS.counter(f"kernel_dispatch:{kernel}:interpret").inc()
     return pallas
+
+
+def impl_name(pallas: bool) -> str:
+    """The implementation :func:`use_pallas` chose, as its counter names it:
+    ``pallas``, ``interpret`` (Pallas off the chip) or ``xla``."""
+    if not pallas:
+        return "xla"
+    return "pallas" if on_tpu() else "interpret"
+
+
+def count_h2d(site: str, span, *arrays, **attrs) -> int:
+    """Count the bytes of the host ``arrays`` handed to a device program at
+    ``site`` as ``h2d_bytes:<site>``, and return them.  ``span`` (a live
+    :class:`repro.obs.Span` or None) gets the same number as ``h2d_bytes``,
+    with ``attrs``."""
+    n = sum(int(a.nbytes) for a in arrays)
+    METRICS.counter(f"h2d_bytes:{site}").inc(n)
+    if span is not None:
+        span.set(h2d_bytes=n, **attrs)
+    return n
 
 
 def padded_rows(n: int, block: int) -> int:

@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dispatch import (on_tpu, pad_rows, padded_queries,
-                                    padded_rows, use_pallas)
+from repro.kernels.dispatch import (count_h2d, impl_name, on_tpu, pad_rows,
+                                    padded_queries, padded_rows, use_pallas)
 from repro.kernels.ivf_scan.ivf_scan import ivf_scan_topk_pallas
 from repro.kernels.ivf_scan.ref import scores_ref
 
@@ -40,7 +40,7 @@ def _scan_topk_xla(q: jnp.ndarray, corpus: jnp.ndarray, n_valid: jnp.ndarray,
 
 
 def ivf_scan_topk(q, corpus, k: int, metric: str = "l2", block_n: int = 512,
-                  n_valid: int = -1, force_pallas: bool = False
+                  n_valid: int = -1, force_pallas: bool = False, span=None
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """[Q, d] x [N, d] -> (vals [Q, k'], ids [Q, k']), k' = min(k, n_valid),
     as host arrays.
@@ -48,7 +48,9 @@ def ivf_scan_topk(q, corpus, k: int, metric: str = "l2", block_n: int = 512,
     Rows at positions >= ``n_valid`` (default: all of ``corpus``) are treated
     as padding and excluded from the result; returned indices are always
     < ``n_valid``.  Inputs are padded on the host (``dispatch.pad_rows``), so
-    pass host arrays: a device array makes a round trip.
+    pass host arrays: a device array makes a round trip.  The padded
+    operands' bytes count as ``h2d_bytes:ivf_scan``; ``span`` (a live
+    ``knn.scan`` span, or None) gets them with ``kernel`` and ``impl``.
     """
     n = corpus.shape[0]
     if n_valid < 0 or n_valid > n:
@@ -60,7 +62,10 @@ def ivf_scan_topk(q, corpus, k: int, metric: str = "l2", block_n: int = 512,
     qn = q.shape[0]
     q = pad_rows(q, padded_queries(qn))
     corpus = pad_rows(corpus, padded_rows(n, block_n))
-    if use_pallas("ivf_scan", k, force_pallas):
+    pallas = use_pallas("ivf_scan", k, force_pallas)
+    count_h2d("ivf_scan", span, q, corpus, kernel="ivf_scan",
+              impl=impl_name(pallas))
+    if pallas:
         vals, idx = ivf_scan_topk_pallas(q, corpus, k, metric=metric,
                                          block_n=block_n, n_valid=n_valid,
                                          interpret=not on_tpu())

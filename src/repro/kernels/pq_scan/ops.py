@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dispatch import (on_tpu, pad_rows, padded_queries,
-                                    padded_rows, use_pallas)
+from repro.kernels.dispatch import (count_h2d, impl_name, on_tpu, pad_rows,
+                                    padded_queries, padded_rows, use_pallas)
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
                                            pq_adc_topk_pallas)
 
@@ -79,7 +79,7 @@ def _pq_topk_xla_ext(luts: jnp.ndarray, codes: jnp.ndarray,
 
 def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
                 force_pallas: bool = False, bias=None, row_bucket=None,
-                cscores=None, probe_mask=None
+                cscores=None, probe_mask=None, span=None
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """[Q, M, K] x [N, M] -> (vals [Q, k'], ids [Q, k']), k' = min(k, n_valid),
     as host arrays (inputs are padded on the host: pass host arrays).
@@ -89,7 +89,10 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     < ``n_valid``.  ``cscores`` / ``probe_mask`` require ``row_bucket``
     (see ref.py for the extended score decomposition); with ``probe_mask``,
     per-query positions past that query's probed row count come back as
-    (val=-inf, id=-1) padding."""
+    (val=-inf, id=-1) padding.  The padded operands' bytes count as
+    ``h2d_bytes:pq_scan`` (``pq_scan_ext`` with any extended argument);
+    ``span`` (a live ``knn.scan`` span, or None) gets them with ``kernel``
+    and ``impl``."""
     n = codes.shape[0]
     qn = luts.shape[0]
     if n_valid < 0 or n_valid > n:
@@ -101,12 +104,14 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     ext = any(a is not None for a in (bias, row_bucket, cscores, probe_mask))
     if (cscores is not None or probe_mask is not None) and row_bucket is None:
         raise ValueError("cscores/probe_mask require row_bucket")
-    use_kernel = use_pallas("pq_scan_ext" if ext else "pq_scan", k,
-                            force_pallas)
+    kernel = "pq_scan_ext" if ext else "pq_scan"
+    use_kernel = use_pallas(kernel, k, force_pallas)
     rows, qrows = padded_rows(n, block_n), padded_queries(qn)
     luts = pad_rows(luts, qrows, np.float32)
     codes = pad_rows(codes, rows)
     if not ext:
+        count_h2d(kernel, span, luts, codes, kernel=kernel,
+                  impl=impl_name(use_kernel))
         if use_kernel:
             v, i = pq_adc_topk_pallas(luts, codes, k, block_n=block_n,
                                       n_valid=n_valid,
@@ -125,6 +130,8 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
                   np.float32)
     pm = pad_rows(np.ones((qn, mb)) if probe_mask is None else probe_mask,
                   qrows, np.float32)
+    count_h2d(kernel, span, luts, codes, bias, rb, cs, pm, kernel=kernel,
+              impl=impl_name(use_kernel))
     if use_kernel:
         v, i = pq_adc_topk_ext_pallas(luts, codes, bias, rb, cs, pm, k,
                                       block_n=block_n, n_valid=n_valid,

@@ -12,7 +12,7 @@ Three layers (ISSUE 10):
   annotation plus a cost-model predicted-vs-observed drift report.
 """
 
-from .trace import Span, Trace, Tracer
+from .trace import Span, Trace, Tracer, maybe_span
 from .metrics import (
     Counter,
     Gauge,
@@ -28,6 +28,7 @@ __all__ = [
     "Span",
     "Trace",
     "Tracer",
+    "maybe_span",
     "Counter",
     "Gauge",
     "Histogram",

@@ -14,10 +14,18 @@ Design contract (see ISSUE 10):
   explicit ``parent=`` handle.
 - Spans are always closed: ``__exit__`` runs on any exception and stamps
   the error type on the span before re-raising.
+- A live span (``trace.span``) also opens a ``jax.profiler.TraceAnnotation``
+  named ``pandadb.<name>`` for the same interval on the same thread, with
+  the span's opening attributes as its metadata.  While a profiler capture
+  runs, the program's spans therefore sit on the device trace's clock,
+  beside the device's operations; outside a capture the annotation records
+  nothing.  After-the-fact spans (``add_timed``) and events are not
+  annotated.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -75,23 +83,34 @@ class Span:
         return f"Span({self.name!r}, {state}, attrs={self.attrs!r})"
 
 
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """The profiler's host annotation for a live span.  ``jax`` is imported
+    here, on first use, so that ``obs`` imports without it."""
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(f"pandadb.{name}", **attrs)
+
+
 class _SpanCtx:
-    """Context manager returned by ``Trace.span``.  Closes the span on any
-    exit path and records the exception type if one escaped."""
+    """Context manager returned by ``Trace.span``.  Closes the span and its
+    profiler annotation on any exit path and records the exception type if
+    one escaped."""
 
-    __slots__ = ("_trace", "_span")
+    __slots__ = ("_trace", "_span", "_ann")
 
-    def __init__(self, trace: "Trace", span: Span):
+    def __init__(self, trace: "Trace", span: Span, ann):
         self._trace = trace
         self._span = span
+        self._ann = ann
 
     def __enter__(self) -> Span:
+        self._ann.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if exc_type is not None:
             self._span.attrs["error"] = exc_type.__name__
         self._trace._close(self._span)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -148,8 +167,11 @@ class Trace:
 
     # -- public API -----------------------------------------------------
     def span(self, name: str, parent: Optional[Span] = None, **attrs: Any) -> _SpanCtx:
-        """``with trace.span("op", k=v) as sp: ...`` — nested, always closed."""
-        return _SpanCtx(self, self._open(name, attrs, parent))
+        """``with trace.span("op", k=v) as sp: ...`` — nested, always closed,
+        and annotated ``pandadb.op`` (metadata ``k=v``) in a profiler
+        capture."""
+        return _SpanCtx(self, self._open(name, attrs, parent),
+                        _annotation(name, attrs))
 
     def event(self, name: str, parent: Optional[Span] = None, **attrs: Any) -> Span:
         """Zero-duration child span marking an instant (hedge fired, shed, ...)."""
@@ -236,6 +258,15 @@ class Trace:
 
     def to_dict(self) -> Dict[str, Any]:
         return {"trace_id": self.trace_id, "root": self.root.to_dict()}
+
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def maybe_span(trace: Optional[Trace], name: str, **attrs: Any):
+    """``trace.span(name, **attrs)``, or, where the work is not traced
+    (``trace is None``), a context that yields None and annotates nothing."""
+    return _NO_SPAN if trace is None else trace.span(name, **attrs)
 
 
 class Tracer:

@@ -194,8 +194,8 @@ class QueryServer:
             return
         self._started = True
         self._stats.started = time.perf_counter()
-        for _ in range(self.n_workers):
-            t = threading.Thread(target=self._worker, daemon=True)
+        for i in range(self.n_workers):
+            t = threading.Thread(target=self._worker, args=(i,), daemon=True)
             t.start()
             self._workers.append(t)
 
@@ -286,7 +286,7 @@ class QueryServer:
 
     # -- execution -------------------------------------------------------------
 
-    def _worker(self) -> None:
+    def _worker(self, index: int) -> None:
         # one session per worker.  Statement reuse needs no worker-local
         # cache: session.run() resolves parse+optimize through the db-level
         # PlanCache by query skeleton, so any worker's prepared skeleton
@@ -298,41 +298,32 @@ class QueryServer:
             req = self._queue.get()
             if req is None:
                 return
-            self._execute(session, req)
+            self._execute(session, req, index)
 
-    def _execute(self, session, req: _ServeRequest) -> None:
+    def _execute(self, session, req: _ServeRequest, worker: int) -> None:
         t0 = time.perf_counter()
         qms = (t0 - req.t_submit) * 1000
         trace = req.trace
-        if trace is not None:
+        if trace is None:
+            out = self._run(session, req, t0)
+        else:
             # the queue wait, after the fact: admission -> worker pickup
             trace.add_timed("queue.wait", qms / 1000, parent=trace.root)
+            # pickup -> done on this worker's thread; of that, ``cpu_ms`` ran
+            # on a CPU, and the rest the worker waited (the interpreter
+            # lock, other threads, the device)
+            with trace.span("serve.execute", parent=trace.root,
+                            worker=worker) as sp:
+                cpu0 = time.thread_time()
+                out = self._run(session, req, t0)
+                sp.set(cpu_ms=1e3 * (time.thread_time() - cpu0))
+            trace.finish()
         d = req.deadline
-        if d is not None and d.expired():
-            # budget burned in the queue; do not occupy the worker
-            self._count("expired")
-            if trace is not None:
-                trace.event("degradation", step="expired_in_queue")
-                trace.finish()
+        if out is None:
             req.done(([], DeadlineExceeded(
                 "queued", d.budget_s * 1000, d.elapsed() * 1000)))
             return
-        degradations: List[str] = []
-        try:
-            cur = session.run(req.text, req.params, optimized=req.optimized,
-                              deadline_ms=d, trace=trace)
-            rows = cur.fetchall()
-            degradations = cur.degradations
-            err: Optional[BaseException] = None
-        except DeadlineExceeded as e:
-            rows, err = [], e
-            self._count("expired")
-        except Exception as e:  # noqa: BLE001 -- surfaced to the caller
-            rows, err = [], e
-            self._count("failed")
-        dt = time.perf_counter() - t0
-        if trace is not None:
-            trace.finish()
+        rows, err, degradations, dt = out
         if err is None:
             self._count("completed")
             if degradations:
@@ -354,6 +345,32 @@ class QueryServer:
             self._stats.queue_ms.append(qms)
             self._stats.e2e_ms.append(qms + dt * 1000)
         req.done((rows, err))
+
+    def _run(self, session, req: _ServeRequest, t0: float):
+        """Execute one picked-up request: ``(rows, error, degradations,
+        seconds since pickup)``, or None where its budget burned in the
+        queue (the worker is then not occupied)."""
+        d, trace = req.deadline, req.trace
+        if d is not None and d.expired():
+            self._count("expired")
+            if trace is not None:
+                trace.event("degradation", parent=trace.root,
+                            step="expired_in_queue")
+            return None
+        degradations: List[str] = []
+        try:
+            cur = session.run(req.text, req.params, optimized=req.optimized,
+                              deadline_ms=d, trace=trace)
+            rows = cur.fetchall()
+            degradations = cur.degradations
+            err: Optional[BaseException] = None
+        except DeadlineExceeded as e:
+            rows, err = [], e
+            self._count("expired")
+        except Exception as e:  # noqa: BLE001 -- surfaced to the caller
+            rows, err = [], e
+            self._count("failed")
+        return rows, err, degradations, time.perf_counter() - t0
 
     # -- load drivers ----------------------------------------------------------
 
