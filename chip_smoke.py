@@ -14,9 +14,10 @@ points a user calls:
               match a sequential run, and the similarity answer a numpy
               cosine reference.
   (c) knn     ``search_many`` at Q in {32, 256}, k=10, modes float / adc /
-              fused, ``rerank_mult`` 8 (k'=80: the XLA twin) and 4 (k'=40:
-              the Pallas kernel); recall@10 >= 0.95 against ``search_exact``,
-              which must itself match a float64 numpy brute force.
+              fused, ``rerank_mult`` 8 (k'=80) and 4 (k'=40), both on the
+              Pallas kernel, and fused at 16 (k'=160: the XLA twin);
+              recall@10 >= 0.95 against ``search_exact``, which must
+              itself match a float64 numpy brute force.
   (d) kernels ``ivf_scan``, ``pq_scan`` (base and extended) and
               ``topk_merge`` at N=131072, d=128, M=16, K=256 against their
               numpy oracles: ids equal except at near-ties inside a stated
@@ -239,7 +240,7 @@ def knn(sm: Smoke, index, phi, persons: int, qs=QUERY_SIZES):
         sm.check(r_ex >= 0.95, f"knn: search_exact recall {r_ex} Q={qn}")
         facts[f"exact_vs_numpy_recall_q{qn}"] = r_ex
         for mode, rm in (("float", None), ("adc", 8), ("adc", 4),
-                         ("fused", 8), ("fused", 4)):
+                         ("fused", 8), ("fused", 4), ("fused", 16)):
             for rep in ("cold", "warm"):
                 t0, c0 = time.perf_counter(), sm.compiles
                 _, ids = index.search_many(q, K, mode=mode, rerank_mult=rm)
@@ -273,7 +274,7 @@ def _near_tie_check(sm, name, vals, ids, exact, tol):
 
 
 def kernels(sm: Smoke, rows: int = KERNEL_ROWS, qs=QUERY_SIZES,
-            ks=(10, 64)):
+            ks=(10, 64, 80)):
     from repro.kernels.ivf_scan.ops import ivf_scan_topk
     from repro.kernels.pq_scan.ops import pq_adc_topk
     from repro.kernels.pq_scan.ref import pq_adc_topk_ref, pq_scores_ref
@@ -444,7 +445,7 @@ def main(argv=None) -> int:
         sm.check(counts.get(f"{kernel}:pallas", 0) > 0,
                  f"dispatch: {kernel} Pallas kernel never ran")
     sm.check(counts.get("pq_scan_ext:xla", 0) > 0,
-             "dispatch: the default k'=80 path never took the XLA twin")
+             "dispatch: the fused k'=160 path never took the XLA twin")
     sm.check(not any(key.endswith(":interpret") for key in counts),
              "dispatch: a kernel ran in interpret mode on the chip")
     stats = sm.device.memory_stats() or {}

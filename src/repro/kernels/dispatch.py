@@ -2,10 +2,14 @@
 
 Every kNN wrapper (``ivf_scan``, ``pq_scan``, ``topk_merge``) asks
 :func:`use_pallas` once per call.  The Pallas kernel runs on a TPU, or where
-a caller forces it (the interpret-mode tests), for k up to ``KERNEL_MAX_K``:
-above that the L max-extract sweeps stop paying for themselves and the
-jitted XLA twin runs instead.  Each decision is counted in ``METRICS`` (the
-``pandadb`` namespace) as ``kernel_dispatch:<kernel>:pallas`` or ``:xla``;
+a caller forces it (the interpret-mode tests), for k up to its kernel's cap
+in ``KERNEL_MAX_K``; above it the jitted XLA twin runs instead.  The
+extended PQ scan ``pq_scan_ext`` takes the kernel up to k = 128, the widest
+at which it measured faster than its twin (one gather pass over a [Q, N]
+float32 score matrix per subspace) at every batch size of the SIFT cell on
+a TPU v5e (PERF.md); the other kernels keep 64, a bound set before any chip
+measurement.  Each decision is counted in ``METRICS`` (the ``pandadb``
+namespace) as ``kernel_dispatch:<kernel>:pallas`` or ``:xla``;
 a Pallas call that runs in interpret mode also counts ``:interpret``, so a
 run on the chip can show which code executed and that none of it was
 interpreted.
@@ -25,7 +29,8 @@ from jax import monitoring
 
 from repro.obs.metrics import MetricsRegistry
 
-KERNEL_MAX_K = 64
+KERNEL_MAX_K = {"ivf_scan": 64, "pq_scan": 64, "pq_scan_ext": 128,
+                "topk_merge": 64}
 
 METRICS = MetricsRegistry("pandadb")
 
@@ -46,7 +51,7 @@ def use_pallas(kernel: str, k: int, force_pallas: bool = False) -> bool:
     """Pick Pallas vs the XLA twin for one ``kernel`` call of width ``k``
     and count the choice."""
     tpu = on_tpu()
-    pallas = (force_pallas or tpu) and k <= KERNEL_MAX_K
+    pallas = (force_pallas or tpu) and k <= KERNEL_MAX_K[kernel]
     METRICS.counter(f"kernel_dispatch:{kernel}:"
                     f"{'pallas' if pallas else 'xla'}").inc()
     if pallas and not tpu:

@@ -2,8 +2,10 @@
 
 The kernel path is exact for any k (per-tile top-k >= global contribution of
 that tile), so parity with ref.py is bitwise on candidate ids (the LUT sums
-are the same fp32 adds in a different order).  Large k' (> 64) falls back to
-the XLA path: the L max-extract sweeps stop paying for themselves.
+are float32 adds in a different order).  Above the kernel's cap
+(``dispatch.KERNEL_MAX_K``: k' 128 for the extended scan, the widest at
+which it measured faster than the twin on the chip; 64 for the base scan)
+the XLA path runs.
 
 Code tables are rarely block_n multiples, so the wrapper pads the codes up
 to one and passes ``n_valid`` through: padded rows are masked to ``NEG``

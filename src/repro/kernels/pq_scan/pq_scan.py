@@ -1,29 +1,40 @@
 """PQ ADC scan Pallas kernel: fused LUT-sum + per-tile top-L (TPU).
 
-The bandwidth-bound half of the PQ kNN hot loop: corpus *codes* (uint8, M
-bytes per row instead of 4d float bytes) stream HBM -> VMEM in block_n
-tiles; each grid step turns its code tile into a one-hot [BN, M*K] matrix
-in registers (an iota compare -- no gather, which the MXU path cannot do
-cheaply) and contracts it against the flattened query LUTs [Q, M*K] with
-ONE MXU matmul, yielding the [Q, BN] ADC score tile.  Tile-local top-L then
-runs the same L vectorized max/mask sweeps as ``ivf_scan`` -- no
-data-dependent control flow, no cross-tile traffic -- and a tiny jnp
-epilogue merges the [n_tiles, L] partials.
+The bandwidth-bound half of the PQ kNN hot loop: corpus *codes* (M bytes
+per row instead of 4d float bytes) stream HBM -> VMEM in block_n tiles;
+each grid step turns its code tile into a one-hot [M*K, BN] matrix (an iota
+compare -- no gather, which the MXU path cannot do cheaply) and contracts
+the flattened query LUTs [Q, M*K] against it on the MXU, yielding the
+[Q, BN] ADC score tile.  A bitonic network then keeps the tile's top-L
+(``sweep.bitonic_topl``, L <= 128) -- no cross-tile traffic -- and a jnp
+epilogue, inside the same jitted program, merges the [n_tiles, L] partials.
 
 The *extended* kernel adds the residual / fused score decomposition
 
     s[q, n] = LUT sum + bias[n] + cscores[q, row_bucket[n]],
-    masked to -inf where probe_mask[q, row_bucket[n]] is False
+    masked to NEG where probe_mask[q, row_bucket[n]] is False
 
-with the same one-hot trick on the bucket axis: a [BN, MB] bucket one-hot
-contracts against ``cscores`` / ``probe_mask`` [Q, MB] in two more MXU
-passes -- no per-lane gather, and the fused probe->ADC->top-k pipeline can
-scan the *whole* code table in one call with non-probed buckets masked
-in-kernel.
+with the same one-hot trick on the bucket axis: a [128, BN] one-hot of the
+tile's bucket ids contracts against a 128-bucket block of ``cscores`` /
+``probe_mask``.  A tile only visits the blocks its rows' buckets fall in
+(their range per tile rides in as scalar prefetch), so a code table sorted
+by bucket -- as a compacted index is -- pays for one or two blocks per tile
+instead of the whole bucket axis, and an unsorted one is still exact.
 
-VMEM working set per grid step (Q<=128, BN=512, M=8, K=256, fp32):
-  luts 128x2048 (1 MB) + codes 512x8 (16 kB int32) + onehot 512x2048 (4 MB)
-  + scores 128x512 (256 kB)  -> comfortably under the ~16 MB VMEM budget.
+Precision.  Scores are float32 sums, as the XLA twin's gathers give: the
+one-hot is exact in bfloat16, and every float operand (LUTs, centroid
+terms) is split on the way in into three bfloat16 pieces whose sum is the
+float32 value exactly (:func:`split3`), so three single bf16 MXU passes
+with float32 accumulation carry all 24 significand bits.  The centroid
+term comes out bit-identical to the gather (one nonzero product per
+column); the LUT sum differs from a sequential float32 sum only by the
+order of its float32 roundings.
+
+VMEM: the LUT pieces and bucket blocks stay resident (one copy each); the
+one-hot, the score pieces and the sweep state are per tile.  At Q=256,
+M=16, K=256, BN=512, 1 420 buckets that is ~27 MiB, past the 16 MiB
+default scoped limit, so each call sets its limit from its own shapes
+(:func:`_vmem_limit`).
 """
 from __future__ import annotations
 
@@ -36,50 +47,101 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sweep import (NEG, merge_tiles, n_valid_operand,
-                                 tile_outputs, tile_topl)
+                                 tile_outputs, tile_topl_sorted)
+
+BUCKET_BLOCK = 128      # buckets per block of the bucket axis (one lane row)
+_Q_ALIGN = 16           # bf16 sublane tile: query pieces stack on it
+_HI16 = -65536          # 0xFFFF0000 as int32: a float32's bf16-exact top half
 
 
-def _adc_tile(luts_ref, codes_ref, ksub: int):
-    """[Q, BN] ADC scores of one code tile: one-hot the codes,
-    onehot[n, j*K + c] = (codes[n, j] == c), and contract against the
-    flattened LUTs.  An iota compare keeps everything dense/vectorized --
-    the TPU has no cheap per-lane gather, but a [Q, M*K] x [M*K, BN]
-    contraction is one MXU pass."""
-    codes = codes_ref[...].astype(jnp.int32)              # [BN, M]
-    bn, m = codes.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bn, m, ksub), 2)
-    onehot = (codes[:, :, None] == iota).astype(jnp.float32)
-    onehot = onehot.reshape(bn, m * ksub)
-    return jax.lax.dot_general(luts_ref[...], onehot,
-                               (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+def split3(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 [R, C] -> bfloat16 [3R, C], rows (hi; mid; lo) with
+    hi + mid + lo == x exactly.  Each piece is cut with a bit mask, never a
+    float round trip, so the compiler's excess-precision rewrites (which
+    may drop an f32 -> bf16 -> f32 pair) cannot fold it away."""
+    def top16(v):
+        return jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(v, jnp.int32) & _HI16, jnp.float32)
+    x = x.astype(jnp.float32)
+    hi = top16(x)
+    r = x - hi                      # the low 16 significand bits, exact
+    mid = top16(r)
+    lo = r - mid                    # at most 8 significant bits
+    return jnp.concatenate([hi, mid, lo]).astype(jnp.bfloat16)
+
+
+def _lut_scores(luts_ref, codes_ref, ksub: int, qp: int) -> jnp.ndarray:
+    """[Q, BN] float32 ADC scores of one code tile: one-hot the codes,
+    onehot[j*K + c, n] = (codes[j, n] == c), and contract the three LUT
+    pieces [3Q, M*K] against it in one MXU call."""
+    codes = codes_ref[...]                                 # [M, BN] int32
+    m, bn = codes.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (m, ksub, bn), 1)
+    onehot = jnp.where(codes[:, None, :] == iota, 1.0, 0.0)
+    onehot = onehot.astype(jnp.bfloat16).reshape(m * ksub, bn)
+    r = jnp.dot(luts_ref[...], onehot, preferred_element_type=jnp.float32)
+    return (r[2 * qp:] + r[qp:2 * qp]) + r[:qp]            # (lo+mid)+hi
 
 
 def _pq_kernel(nv_ref, luts_ref, codes_ref, vals_ref, idx_ref, *, topl: int,
-               block_n: int, ksub: int):
-    s = _adc_tile(luts_ref, codes_ref, ksub)                      # [Q, BN]
-    tile_topl(s, nv_ref, block_n, topl, vals_ref, idx_ref)
+               block_n: int, ksub: int, qp: int):
+    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
+    tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
 
 
-def _pq_kernel_ext(nv_ref, luts_ref, codes_ref, bias_ref, rb_ref, cs_ref,
-                   pm_ref, vals_ref, idx_ref, *, topl: int, block_n: int,
-                   ksub: int, mb: int):
-    s = _adc_tile(luts_ref, codes_ref, ksub)                      # [Q, BN]
-    # bucket terms: one-hot the per-row bucket id and contract the per-query
-    # centroid scores / probe mask against it -- two more MXU passes instead
-    # of a per-lane gather
-    rb = rb_ref[...]                                      # [1, BN] int32
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (mb, rb.shape[1]), 0)
-    onehot_b = (iota_b == rb).astype(jnp.float32)                 # [MB, BN]
-    cterm = jax.lax.dot_general(cs_ref[...], onehot_b,
-                                (((1,), (0,)), ((), ())),
+def _pq_kernel_ext(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref, bias_ref,
+                   rb_ref, bt_ref, vals_ref, idx_ref, acc_ref, *, topl: int,
+                   block_n: int, ksub: int, qp: int):
+    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
+    # bucket terms: for each 128-bucket block this tile's rows fall in,
+    # one-hot the rows' bucket ids and contract the block's centroid-term
+    # pieces and probe mask [4Q, 128] against it
+    rb = rb_ref[...]                                       # [1, BN] int32
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def block(b, carry):
+        iota = jax.lax.broadcasted_iota(jnp.int32, (BUCKET_BLOCK, block_n),
+                                        0) + b * BUCKET_BLOCK
+        onehot = jnp.where(iota == rb, 1.0, 0.0).astype(jnp.bfloat16)
+        acc_ref[...] += jnp.dot(bt_ref[b], onehot,
                                 preferred_element_type=jnp.float32)
-    mterm = jax.lax.dot_general(pm_ref[...], onehot_b,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    s = s + cterm + bias_ref[...]                         # bias tile [1, BN]
-    s = jnp.where(mterm > 0.5, s, NEG)
-    tile_topl(s, nv_ref, block_n, topl, vals_ref, idx_ref)
+        return carry
+
+    i = pl.program_id(0)
+    jax.lax.fori_loop(blo_ref[i], bhi_ref[i] + 1, block, 0)
+    acc = acc_ref[...]
+    cterm = (acc[2 * qp:3 * qp] + acc[qp:2 * qp]) + acc[:qp]
+    s = (s + bias_ref[...]) + cterm                       # bias tile [1, BN]
+    s = jnp.where(acc[3 * qp:] > 0.5, s, NEG)
+    tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
+
+
+def _prep(luts: jnp.ndarray, codes: jnp.ndarray, block_n: int):
+    """Shared operand prep: query rows padded to the bf16 sublane tile, the
+    LUTs flattened and split into their three bf16 pieces, the codes laid
+    out [M, N] so each tile's one-hot is a sublane broadcast."""
+    qn, m, ksub = luts.shape
+    n = codes.shape[0]
+    assert codes.shape[1] == m, (codes.shape, m)
+    assert n % block_n == 0, (n, block_n)
+    qp = -(-qn // _Q_ALIGN) * _Q_ALIGN
+    luts = jnp.pad(luts.astype(jnp.float32), ((0, qp - qn), (0, 0), (0, 0)))
+    luts3 = split3(luts.reshape(qp, m * ksub))
+    return qp, luts3, codes.astype(jnp.int32).T
+
+
+def _vmem_limit(qp: int, mk: int, block_n: int, k: int,
+                bucket_bytes: int = 0) -> int:
+    """Scoped VMEM for one call, from its shapes: the resident LUT pieces
+    and bucket blocks, the tile's one-hot (float32 compare and bf16 copy),
+    score pieces, bucket accumulator and sweep state, and double-buffered
+    tiles; with half again as headroom."""
+    need = (3 * qp * mk * 2 + bucket_bytes          # resident operands
+            + mk * block_n * 6                      # one-hot
+            + (3 + 4 + 4) * qp * block_n * 4        # pieces, acc, sweep
+            + 2 * (mk // 16 + 2) * block_n * 4      # code/bias/bucket tiles
+            + 2 * 2 * qp * k * 4)                   # out tiles
+    return int(min(max(need * 3 // 2, 32 << 20), 100 << 20))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -95,27 +157,28 @@ def pq_adc_topk_pallas(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
     the top-k.  The caller keeps k <= n_valid."""
     qn, m, ksub = luts.shape
     n = codes.shape[0]
-    assert codes.shape[1] == m, (codes.shape, m)
-    assert n % block_n == 0, (n, block_n)
+    qp, luts3, codes_t = _prep(luts, codes, block_n)
     n_tiles = n // block_n
-    luts_flat = luts.astype(jnp.float32).reshape(qn, m * ksub)
-    out_specs, out_shape = tile_outputs(n_tiles, qn, k)
+    out_specs, out_shape = tile_outputs(n_tiles, qp, k)
 
     kernel = functools.partial(_pq_kernel, topl=k, block_n=block_n,
-                               ksub=ksub)
+                               ksub=ksub, qp=qp)
     vals, idx = pl.pallas_call(
         kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # n_valid
-            pl.BlockSpec((qn, m * ksub), lambda i: (0, 0)),  # luts: resident
-            pl.BlockSpec((block_n, m), lambda i: (i, 0)),    # code tile
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                       # n_valid
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),   # LUT pieces
+                pl.BlockSpec((m, block_n), lambda i, nv: (0, i)),  # codes
+            ],
+            out_specs=out_specs),
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(qp, m * ksub, block_n, k)),
         interpret=interpret,
-    )(n_valid_operand(n_valid, n), luts_flat, codes.astype(jnp.int32))
-    return merge_tiles(vals, idx, k)
+    )(n_valid_operand(n_valid, n), luts3, codes_t)
+    return merge_tiles(vals[:, :qn], idx[:, :qn], k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
@@ -132,36 +195,55 @@ def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
     qn, m, ksub = luts.shape
     n = codes.shape[0]
     mb = cscores.shape[1]
-    assert codes.shape[1] == m, (codes.shape, m)
-    assert n % block_n == 0, (n, block_n)
     assert probe_mask.shape == cscores.shape, (probe_mask.shape,
                                                cscores.shape)
+    qp, luts3, codes_t = _prep(luts, codes, block_n)
     n_tiles = n // block_n
-    luts_flat = luts.astype(jnp.float32).reshape(qn, m * ksub)
-    out_specs, out_shape = tile_outputs(n_tiles, qn, k)
+    nblk = -(-mb // BUCKET_BLOCK)
+    out_specs, out_shape = tile_outputs(n_tiles, qp, k)
+
+    # bucket operand: (cscores hi; mid; lo; probe mask) rows, cut into
+    # 128-bucket blocks [nblk, 4Q, 128] so a block is a leading-axis index
+    pad = ((0, qp - qn), (0, nblk * BUCKET_BLOCK - mb))
+    bt = jnp.concatenate([
+        split3(jnp.pad(cscores.astype(jnp.float32), pad)),
+        jnp.pad(probe_mask.astype(jnp.float32), pad).astype(jnp.bfloat16)])
+    bt = bt.reshape(4 * qp, nblk, BUCKET_BLOCK).transpose(1, 0, 2)
+    # each tile's range of bucket blocks over its real rows; an all-padding
+    # tile gets an empty range
+    nv = n_valid_operand(n_valid, n)
+    rb = row_bucket.astype(jnp.int32)
+    real = jnp.arange(n) < nv[0]
+    blo = jnp.min(jnp.where(real, rb, mb).reshape(n_tiles, block_n), axis=1)
+    bhi = jnp.max(jnp.where(real, rb, -1).reshape(n_tiles, block_n), axis=1)
+    blo = jnp.clip(blo // BUCKET_BLOCK, 0, nblk)
+    bhi = jnp.clip(bhi // BUCKET_BLOCK, -1, nblk - 1)
 
     kernel = functools.partial(_pq_kernel_ext, topl=k, block_n=block_n,
-                               ksub=ksub, mb=mb)
+                               ksub=ksub, qp=qp)
+    tile = lambda i, *_: (0, i)                                  # noqa: E731
     # bias and row_bucket ride as [1, N] rows: 1-D tiles of block_n lanes
     # have no TPU layout XLA and Mosaic agree on, and an [N, 1] column
     # would pad every row out to 128 lanes in HBM
     vals, idx = pl.pallas_call(
         kernel,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # n_valid
-            pl.BlockSpec((qn, m * ksub), lambda i: (0, 0)),  # luts: resident
-            pl.BlockSpec((block_n, m), lambda i: (i, 0)),    # code tile
-            pl.BlockSpec((1, block_n), lambda i: (0, i)),    # bias tile
-            pl.BlockSpec((1, block_n), lambda i: (0, i)),    # bucket tile
-            pl.BlockSpec((qn, mb), lambda i: (0, 0)),        # cscores: res
-            pl.BlockSpec((qn, mb), lambda i: (0, 0)),        # mask: res
-        ],
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                  # n_valid, block ranges
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.VMEM),   # LUT pieces
+                pl.BlockSpec((m, block_n), tile),        # code tile
+                pl.BlockSpec((1, block_n), tile),        # bias tile
+                pl.BlockSpec((1, block_n), tile),        # bucket tile
+                pl.BlockSpec(memory_space=pltpu.VMEM),   # bucket blocks
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((4 * qp, block_n), jnp.float32)]),
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(qp, m * ksub, block_n, k,
+                                         bucket_bytes=bt.size * 2)),
         interpret=interpret,
-    )(n_valid_operand(n_valid, n), luts_flat, codes.astype(jnp.int32),
-      bias.astype(jnp.float32)[None, :],
-      row_bucket.astype(jnp.int32)[None, :], cscores.astype(jnp.float32),
-      probe_mask.astype(jnp.float32))
-    return merge_tiles(vals, idx, k)
+    )(nv, blo.astype(jnp.int32), bhi.astype(jnp.int32), luts3, codes_t,
+      bias.astype(jnp.float32)[None, :], rb[None, :], bt)
+    return merge_tiles(vals[:, :qn], idx[:, :qn], k)

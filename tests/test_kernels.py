@@ -459,6 +459,99 @@ def test_pq_ext_block_padding(force_pallas):
     assert np.array_equal(np.asarray(i1), i2)
 
 
+def _wide_ext_inputs(data, order, qn=5, n=1400, m=8, ksub=64, mb=24):
+    """The fused scan's operands at the kernel's widest k': a ragged code
+    table (padded to 1 536 rows, n_valid 1 400) whose rows sit in bucket
+    order (as a compacted index keeps them) or shuffled; ``ties`` draws
+    small integers, so many rows score exactly alike and every sum is
+    exact in float32.  Query 0 probes one 8-row bucket: fewer rows than
+    any k' here, so its tail is (-inf, -1)."""
+    rng = np.random.default_rng([data == "ties", order == "shuffled"])
+    if data == "ties":
+        luts = rng.integers(-4, 4, (qn, m, ksub)).astype(np.float32)
+        bias = rng.integers(-2, 2, n).astype(np.float32)
+        cs = rng.integers(-3, 3, (qn, mb)).astype(np.float32)
+    else:
+        luts = rng.standard_normal((qn, m, ksub)).astype(np.float32)
+        bias = rng.standard_normal(n).astype(np.float32)
+        cs = rng.standard_normal((qn, mb)).astype(np.float32)
+    codes = rng.integers(0, ksub, (n, m)).astype(np.int32)
+    rb = np.sort(np.concatenate([np.zeros(8), rng.integers(1, mb, n - 8)])
+                 ).astype(np.int32)
+    if order == "shuffled":
+        rb = rng.permutation(rb)
+    pm = rng.random((qn, mb)) < 0.5
+    pm[0, :] = False
+    pm[0, 0] = True
+    return luts, codes, bias, rb, cs, pm
+
+
+@pytest.mark.parametrize("k", [65, 80, 128])
+@pytest.mark.parametrize("data", ["float", "ties"])
+@pytest.mark.parametrize("order", ["sorted", "shuffled"])
+def test_pq_ext_kernel_wide_k_parity(k, data, order):
+    """k' in (64, 128] now takes the Pallas kernel: probe mask, residual
+    bias and centroid terms, a ragged n_valid tail, a starved query and
+    exact ties (lower row first) all match the oracle."""
+    from repro.kernels.dispatch import dispatch_counts
+    luts, codes, bias, rb, cs, pm = _wide_ext_inputs(data, order)
+    before = dispatch_counts().get("pq_scan_ext:pallas", 0)
+    v1, i1 = pq_adc_topk(luts, codes, k, bias=bias, row_bucket=rb,
+                         cscores=cs, probe_mask=pm, force_pallas=True)
+    assert dispatch_counts()["pq_scan_ext:pallas"] == before + 1
+    v2, i2 = pq_adc_topk_ref(luts, codes, k, bias=bias, row_bucket=rb,
+                             cscores=cs, probe_mask=pm)
+    if data == "ties":
+        assert np.array_equal(v1, v2)
+        assert len(np.unique(v2[np.isfinite(v2)])) < np.isfinite(v2).sum()
+    else:
+        np.testing.assert_allclose(v1, v2, rtol=1e-5, atol=1e-5)
+    assert np.array_equal(i1, i2)
+    assert (i1[0, :8] >= 0).all() and (i1[0, 8:] == -1).all()
+    assert np.isinf(v1[0, 8:]).all()
+
+
+@pytest.mark.parametrize("q,c,topl", [(8, 128, 1), (16, 256, 10),
+                                      (8, 512, 80), (16, 512, 128),
+                                      (8, 1024, 64)])
+@pytest.mark.parametrize("data", ["float", "ties"])
+def test_bitonic_topl_matches_stable_sort(q, c, topl, data):
+    """The pq kernels' sorting-network top-L: values and columns equal a
+    stable descending sort's head, exact ties and NEG-masked columns
+    included (ties to the lower column, as lax.top_k gives)."""
+    import jax
+    from jax.experimental import pallas as pl
+    from repro.kernels.sweep import NEG, bitonic_topl
+    rng = np.random.default_rng(c + topl)
+    s = (rng.integers(0, 5, (q, c)) if data == "ties"
+         else rng.standard_normal((q, c))).astype(np.float32)
+    s[:, rng.integers(0, c, c // 5)] = NEG
+
+    def kernel(s_ref, v_ref, i_ref):
+        v_ref[...], i_ref[...] = bitonic_topl(s_ref[...], 1000, topl)
+
+    v, i = pl.pallas_call(
+        kernel, interpret=True,
+        out_shape=[jax.ShapeDtypeStruct((q, topl), jnp.float32),
+                   jax.ShapeDtypeStruct((q, topl), jnp.int32)])(
+        jnp.asarray(s))
+    order = np.argsort(-s, axis=1, kind="stable")[:, :topl]
+    assert np.array_equal(np.asarray(v), np.take_along_axis(s, order, 1))
+    assert np.array_equal(np.asarray(i), order + 1000)
+
+
+def test_pq_split3_is_exact():
+    """The kernel's three bfloat16 pieces of a float32 operand sum back to
+    it exactly, so the MXU contraction keeps float32 scores."""
+    from repro.kernels.pq_scan.pq_scan import split3
+    x = np.random.default_rng(5).standard_normal((4, 300)).astype(np.float32)
+    x *= np.float32(10.0) ** np.random.default_rng(6).integers(-6, 6, (4, 1))
+    p = np.asarray(split3(jnp.asarray(x))).astype(np.float32)
+    assert p.shape == (12, 300)
+    back = (p[8:] + p[4:8]) + p[:4]
+    assert np.array_equal(back, x)
+
+
 def test_pq_ext_requires_row_bucket():
     luts, codes, bias, rb, cs, pm = _ext_inputs(2, 100, 4, 16, 4)
     with pytest.raises(ValueError, match="row_bucket"):
@@ -486,13 +579,15 @@ def _dispatch_call(kernel, k):
 
 @pytest.mark.parametrize("kernel", ["ivf_scan", "pq_scan", "pq_scan_ext",
                                     "topk_merge"])
-@pytest.mark.parametrize("k,impl", [(8, "pallas"), (80, "xla")])
-def test_dispatch_counts_each_implementation(kernel, k, impl):
-    """force_pallas takes the kernel up to k=64 and the XLA twin above it;
-    each call counts once under the implementation it took, and a Pallas
-    call off the chip also counts as interpreted."""
+@pytest.mark.parametrize("k", [8, 80, 129])
+def test_dispatch_counts_each_implementation(kernel, k):
+    """force_pallas takes the kernel up to its own cap (64; 128 for the
+    extended PQ scan) and the XLA twin above it; each call counts once under
+    the implementation it took, and a Pallas call off the chip also counts
+    as interpreted."""
     from repro.kernels.dispatch import KERNEL_MAX_K, dispatch_counts
-    assert (k <= KERNEL_MAX_K) == (impl == "pallas")
+    impl = "pallas" if k <= KERNEL_MAX_K[kernel] else "xla"
+    assert KERNEL_MAX_K[kernel] == (128 if kernel == "pq_scan_ext" else 64)
     before = dispatch_counts()
     _dispatch_call(kernel, k)
     after = dispatch_counts()

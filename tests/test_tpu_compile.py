@@ -92,3 +92,20 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, qn, k):
         total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes)
         assert total < 16e9, total
+
+
+def test_pq_scan_ext_compiles_at_served_shape(one_chip):
+    """The fused masked ADC scan at the SIFT cell's widths: Q 256, M 16,
+    N 131 072, 1 420 buckets, k' 80.  Its resident LUT pieces and bucket
+    blocks pass the default scoped VMEM limit, so an overrun at the served
+    shape fails here rather than on the chip."""
+    f32, i32, u8, sh = jnp.float32, jnp.int32, jnp.uint8, one_chip
+    qn, mb, k = 256, 1420, 80
+    fn = lambda l, c, b, rb, cs, pm, nv: pq_adc_topk_ext_pallas(  # noqa: E731
+        l, c, b, rb, cs, pm, k, n_valid=nv, interpret=False)
+    args = (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
+            _spec((N,), f32, sh), _spec((N,), i32, sh),
+            _spec((qn, mb), f32, sh), _spec((qn, mb), f32, sh),
+            _spec((), i32, sh))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
