@@ -30,7 +30,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 from concurrent.futures import ThreadPoolExecutor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -67,7 +67,7 @@ from repro.core.session import (
 )
 from repro.core.vector_index import IVFIndex, scatter_gather_knn
 from repro.obs import MetricsRegistry, QueryProfile, Tracer
-from repro.obs.trace import Trace
+from repro.obs.trace import Trace, maybe_span
 from repro.cluster.partition import ShardMap, make_shard
 from repro.cluster.scatter import (
     ClusterUnsupportedQuery,
@@ -156,6 +156,8 @@ def _apply_op(db: PandaDB, op: str, args: tuple, kw: Dict[str, Any]) -> Any:
         return None
     if op == "index_insert":
         return db.index_insert(*args)
+    if op == "build_scalar_index":
+        return db.build_scalar_index(*args)
     if op == "set_index":
         sub_key, piece = args
         db.indexes[sub_key] = _copy_piece(piece)
@@ -710,12 +712,48 @@ class ShardedPandaDB:
     def index_pieces(self, sub_key: str) -> List[IVFIndex]:
         return [self.read_db(s).indexes[sub_key] for s in self.active]
 
+    def build_scalar_index(self, sub_key: str, prop_key: str) -> None:
+        """Each shard builds ``sub_key``'s scalar index (numeric or
+        inverted, ``PandaDB.build_scalar_index``) over the blobs it owns,
+        so a shard resolves a filter over its own rows only.  A shard that
+        owns no node with ``prop_key`` gets none."""
+        built = False
+        for s in self.active:
+            try:
+                self.read_db(s).blob_ids_for(prop_key)
+            except KeyError:
+                continue
+            self._shard_apply(s, "build_scalar_index", sub_key, prop_key)
+            built = True
+        if not built:
+            raise KeyError(f"no property {prop_key!r}")
+
+    def _resolve_filters(self, filter_key: str,
+                         filters: Sequence[Sequence[str]]
+                         ) -> List[List[np.ndarray]]:
+        """Per shard, each query's allowed blob ids: the AND of its tokens
+        over the shard's inverted index on ``filter_key`` (a shard with no
+        such index allows nothing)."""
+        if not filter_key:
+            raise ValueError("filters need a filter_key")
+        if any(len(f) == 0 for f in filters):
+            raise ValueError("a filter names at least one token")
+        idxs = [self.read_db(s).scalar_indexes.get(filter_key)
+                for s in self.active]
+        if all(ix is None for ix in idxs):
+            raise KeyError(f"no scalar index on {filter_key!r}")
+        none = np.zeros(0, np.int64)
+        return [[none] * len(filters) if ix is None
+                else [ix.lookup_all(f) for f in filters] for ix in idxs]
+
     # -- kNN scatter-gather -----------------------------------------------------
 
     def knn(self, sub_key: str, queries: np.ndarray, k: int,
             nprobe: Optional[int] = None, mode: str = "auto",
             rerank: bool = True, deadline_ms: Optional[float] = None,
-            trace: Optional[Trace] = None
+            trace: Optional[Trace] = None,
+            filters: Optional[Sequence[Sequence[str]]] = None,
+            filter_key: Optional[str] = None
             ) -> Tuple[np.ndarray, np.ndarray]:
         """Scatter-gather kNN over every shard's index piece through the
         shared ``merge_topk`` schedule.  Each shard's scan feeds its own
@@ -724,12 +762,27 @@ class ShardedPandaDB:
         (``stats.record_shard_scan``).  Under a ``deadline_ms`` budget,
         shards that cannot answer in time are dropped and the merge
         returns partial top-k from the shards that did (padding contract:
-        dropped slots are id=-1 / -inf)."""
+        dropped slots are id=-1 / -inf).
+
+        ``filters`` (filtered kNN) gives each query a conjunction of
+        tokens over the scalar index ``filter_key`` (an inverted index from
+        :meth:`build_scalar_index`, e.g. a photo's tags): the query is
+        answered from the blobs that carry every token, and may return
+        fewer than ``k``.  Each shard resolves the tokens on its own index
+        (``knn.filter`` span) and scans its own allowed rows."""
         deadline = Deadline.resolve(deadline_ms)
         own_trace = trace is None and self.tracer.enabled
         if own_trace:
             trace = self.tracer.begin("knn", sub_key=sub_key, k=k)
         try:
+            allow = None
+            if filters is not None:
+                if len(filters) != len(queries):
+                    raise ValueError(f"{len(filters)} filters for "
+                                     f"{len(queries)} queries")
+                with maybe_span(trace, "knn.filter",
+                                queries=len(filters)):
+                    allow = self._resolve_filters(filter_key, filters)
             vals, ids = scatter_gather_knn(
                 self.index_pieces(sub_key), queries, k, nprobe=nprobe,
                 mode=mode, rerank=rerank,
@@ -737,7 +790,7 @@ class ShardedPandaDB:
                 record=self.stats.record_shard_scan,
                 pool=self._pool,
                 split_rerank_budget=self.cfg.cluster.split_rerank_budget,
-                deadline=deadline, trace=trace)
+                deadline=deadline, trace=trace, allow=allow)
         finally:
             if own_trace and trace is not None:
                 trace.finish()

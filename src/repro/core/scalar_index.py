@@ -79,12 +79,19 @@ class InvertedIndex:
         return self.postings.get(str(token).lower(), np.array([], np.int64))
 
     def lookup_all(self, tokens: Iterable[str]) -> np.ndarray:
-        """AND-semantics posting intersection."""
-        out: Optional[np.ndarray] = None
-        for t in tokens:
-            p = self.lookup(t)
-            out = p if out is None else np.intersect1d(out, p)
-        return out if out is not None else np.array([], np.int64)
+        """AND-semantics posting intersection, sorted.  The shortest list
+        leads, and each further list is binary-searched for its
+        survivors: O(shortest x log longest), not a sort of both."""
+        lists = sorted((self.lookup(t) for t in tokens), key=len)
+        if not lists:
+            return np.array([], np.int64)
+        out = lists[0]
+        for p in lists[1:]:
+            if not len(out):
+                break
+            j = np.minimum(np.searchsorted(p, out), len(p) - 1)
+            out = out[p[j] == out] if len(p) else out[:0]
+        return out
 
     def insert(self, tokens: Iterable[str], item_id: int) -> None:
         if isinstance(tokens, str):

@@ -44,9 +44,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.pandadb import VectorIndexConfig
-from repro.kernels.dispatch import count_h2d
+from repro.kernels.dispatch import METRICS, count_h2d
 from repro.kernels.ivf_scan.ops import ivf_scan_topk
-from repro.kernels.pq_scan.ops import pq_adc_topk
+from repro.kernels.pq_scan.ops import pack_row_mask, pq_adc_topk
 from repro.kernels.topk_merge.ops import merge_topk_dev
 from repro.obs.trace import maybe_span
 
@@ -154,7 +154,8 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
                        mode: str = "auto", rerank: bool = True,
                        stats=None, record: Optional[Callable] = None,
                        pool=None, split_rerank_budget: bool = False,
-                       deadline=None, trace=None
+                       deadline=None, trace=None,
+                       allow: Optional[Sequence[Sequence[np.ndarray]]] = None
                        ) -> Tuple[np.ndarray, np.ndarray]:
     """THE cluster merge schedule: per-shard ``search_many`` (ADC, float or
     fused, per each shard's cost-model call) -> one-dispatch k-way
@@ -199,7 +200,12 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     re-rank mode, correct even off pool threads; the shard's
     ``search_many`` nests its phases under it), a ``knn.merge`` span for
     the device-side reduce with the bytes it uploads (``h2d_bytes``), and
-    a ``degradation`` event when the partial top-k ladder step fires."""
+    a ``degradation`` event when the partial top-k ladder step fires.
+
+    ``allow`` (filtered kNN, optional) holds, per shard, each query's
+    allowed external ids (the shard's share of the filter, resolved by
+    the caller): every shard answers each query from those rows only
+    (:meth:`IVFIndex.search_many`), and the merge is unchanged."""
     queries = np.asarray(queries, np.float32)
     qn = queries.shape[0]
     out_v = np.full((qn, k), -np.inf, np.float32)
@@ -220,12 +226,13 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     def scan_one(s: int):
         t0 = time.perf_counter()
         rows0 = shards[s].scan_rows
+        kw = {} if allow is None else {"allow": allow[s]}
         with maybe_span(trace, "knn.shard_scan", parent=t_parent,
                         shard=s) as sp:
             v, i = shards[s].search_many(queries, k, nprobe,
                                          stats=per_stats[s], mode=mode,
                                          rerank=rerank, rerank_mult=rm,
-                                         trace=trace)
+                                         trace=trace, **kw)
             scanned = shards[s].scan_rows - rows0
             if sp is not None:
                 sp.set(rows=int(scanned), rerank=rerank)
@@ -507,6 +514,10 @@ class IVFIndex:
     pending_count: int = 0
     # rows scanned so far (the coordinator's per-shard throughput feed)
     scan_rows: int = 0
+    # external id -> compacted row lookup of filtered searches, built on
+    # the first one after a build or compaction (:meth:`_id_rows`)
+    _row_lookup: Optional[tuple] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def n_total(self) -> int:
@@ -680,6 +691,7 @@ class IVFIndex:
         self._pend_codes.clear()
         self._pend_bias.clear()
         self.pending_count = 0
+        self._row_lookup = None
 
     # -- kNN search -------------------------------------------------------------
 
@@ -724,7 +736,8 @@ class IVFIndex:
     def search_many(self, queries: np.ndarray, k: int,
                     nprobe: Optional[int] = None, stats=None,
                     mode: str = "auto", rerank: bool = True,
-                    rerank_mult: Optional[int] = None, trace=None
+                    rerank_mult: Optional[int] = None, trace=None,
+                    allow: Optional[Sequence[np.ndarray]] = None
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """Batched two-phase kNN over the whole query set.
 
@@ -785,7 +798,15 @@ class IVFIndex:
         ``knn.scan`` per kernel call (``kernel``, ``impl``), ``knn.rerank``
         (row fetch, exact scores, ordering), and ``knn.host_scan`` for the
         single-query host path.  Spans that hand host arrays to a device
-        program carry their bytes as ``h2d_bytes``."""
+        program carry their bytes as ``h2d_bytes``.
+
+        ``allow`` (filtered kNN) gives each query its allowed external ids
+        (a repeated id counts once): a query is answered from those rows
+        only, so it may return fewer
+        than ``k`` (the tail is -inf / -1) and never another row.  A
+        filtered query probes every bucket, the filter being its only mask
+        (``nprobe`` does not apply).  See :meth:`_search_filtered` for the
+        paths it takes."""
         if mode not in ("auto", "adc", "float", "fused"):
             raise ValueError(f"unknown scan mode {mode!r}; "
                              f"expected auto | adc | float | fused")
@@ -793,7 +814,13 @@ class IVFIndex:
         qn = queries.shape[0]
         out_v = np.full((qn, k), -np.inf, np.float32)
         out_i = np.full((qn, k), -1, np.int64)
+        if allow is not None and len(allow) != qn:
+            raise ValueError(f"{len(allow)} filters for {qn} queries")
         if qn == 0 or self.n_total == 0:
+            return out_v, out_i
+        if allow is not None:
+            self._search_filtered(queries, allow, k, mode, stats, out_v,
+                                  out_i, rerank, rerank_mult, trace)
             return out_v, out_i
         m = self.centroids.shape[0]
         nprobe = min(nprobe or self.cfg.nprobe, m)
@@ -1151,6 +1178,19 @@ class IVFIndex:
                 row_bucket=self.bucket_of,
                 cscores=(cterm if residual else None), probe_mask=pm,
                 span=sp)
+        self._rerank_table(queries, vals, idx, k_eff, rerank, out_v, out_i,
+                           trace)
+        return qn * n_real
+
+    def _rerank_table(self, queries: np.ndarray, vals: np.ndarray,
+                      idx: np.ndarray, k_eff: int, rerank: bool,
+                      out_v: np.ndarray, out_i: np.ndarray,
+                      trace=None) -> None:
+        """A whole-table scan's top-k' (table rows; -1 where a query had
+        no candidate) -> ``out_v`` / ``out_i``: the exact re-rank of the
+        candidates (or their ADC scores with ``rerank=False``).  A query
+        with fewer candidates than ``k_eff`` keeps its (-inf, -1) tail."""
+        qn = queries.shape[0]
         idx = idx.astype(np.int64)                           # [Q, k']; -1 pad
         valid = idx >= 0
         safe = np.where(valid, idx, 0)
@@ -1168,7 +1208,145 @@ class IVFIndex:
             gid = self.ids[safe[:, :k_eff]]
         out_v[:, :k_eff] = v
         out_i[:, :k_eff] = np.where(np.isfinite(v), gid, -1)
+
+    # -- filtered kNN -----------------------------------------------------------
+
+    def _search_filtered(self, queries: np.ndarray,
+                         allow: Sequence[np.ndarray], k: int, mode: str,
+                         stats, out_v: np.ndarray, out_i: np.ndarray,
+                         rerank: bool, rerank_mult: Optional[int],
+                         trace=None) -> None:
+        """Filtered kNN over every bucket, each query restricted to its
+        ``allow`` ids.  A batch on a compacted PQ index takes ONE
+        row-masked ADC dispatch over the whole code table
+        (:meth:`_scan_rowmask`), then the exact re-rank.  One query,
+        pending appends, a flat index or ``mode="float"`` take the host
+        path instead (:meth:`_scan_filtered_host`): exact scores of the
+        allowed rows, counted as ``knn_filter_host_queries``.  No path
+        returns a row outside a query's filter; no staged path takes a
+        filter."""
+        t0 = time.perf_counter()
+        if (self.pq is not None and self.codes is not None
+                and mode != "float" and queries.shape[0] > 1
+                and self.pending_count == 0):
+            rows_scanned = self._scan_rowmask(queries, allow, k, out_v,
+                                              out_i, rerank, rerank_mult,
+                                              trace)
+            self._note_scan(stats, time.perf_counter() - t0, rows_scanned,
+                            "fused")
+        else:
+            self.scan_rows += self._scan_filtered_host(queries, allow, k,
+                                                       out_v, out_i, trace)
+
+    def _id_rows(self, ext_ids: np.ndarray) -> np.ndarray:
+        """Compacted table row of each external id, -1 where it has none.
+        The lookup is built once per build or compaction, on the first
+        filtered search after it: a dense table where the ids are dense
+        (a shard of ``P`` holds about every ``P``-th id), else the sorted
+        ids.  The dense table is kept for speed: over 10**5 rows a batch's
+        10**6 lookups cost about a tenth of the binary searches."""
+        lk = self._row_lookup
+        if lk is None:
+            ids = self.ids.astype(np.int64)
+            top = int(ids.max()) + 1 if len(ids) else 0
+            if len(ids) and int(ids.min()) >= 0 and top <= 128 * len(ids):
+                dense = np.full(top, -1, np.int32)
+                dense[ids] = np.arange(len(ids), dtype=np.int32)
+                lk = (dense,)
+            else:
+                order = np.argsort(ids, kind="stable")
+                lk = (ids[order], order)
+            self._row_lookup = lk
+        ext_ids = np.asarray(ext_ids, np.int64)
+        if len(lk) == 1:
+            dense = lk[0]
+            ok = (ext_ids >= 0) & (ext_ids < len(dense))
+            return np.where(ok, dense[np.where(ok, ext_ids, 0)], -1)
+        keys, order = lk
+        if not len(keys):
+            return np.full(len(ext_ids), -1, np.int64)
+        j = np.minimum(np.searchsorted(keys, ext_ids), len(keys) - 1)
+        return np.where(keys[j] == ext_ids, order[j], -1)
+
+    def _allowed_rows(self, allow: Sequence[np.ndarray],
+                      pending: bool = False) -> List[np.ndarray]:
+        """Each query's allowed ids -> its row positions: compacted table
+        rows, then (``pending``) appended rows numbered after them in
+        :meth:`_full_corpus` order.  Ids with no row here are dropped."""
+        counts = np.fromiter((len(a) for a in allow), np.int64, len(allow))
+        flat = (np.concatenate([np.asarray(a, np.int64) for a in allow])
+                if counts.sum() else np.zeros(0, np.int64))
+        pos = self._id_rows(flat)
+        if pending and self.pending_count:
+            pend = np.asarray([i for b in sorted(self._pend_ids)
+                               for i in self._pend_ids[b]], np.int64)
+            order = np.argsort(pend, kind="stable")
+            j = np.minimum(np.searchsorted(pend[order], flat), len(pend) - 1)
+            hit = (pos < 0) & (pend[order][j] == flat)
+            pos = np.where(hit, len(self.ids) + order[j], pos)
+        return [p[p >= 0] for p in np.split(pos, np.cumsum(counts)[:-1])]
+
+    def _scan_rowmask(self, queries: np.ndarray, allow: Sequence[np.ndarray],
+                      k: int, out_v: np.ndarray, out_i: np.ndarray,
+                      rerank: bool, rerank_mult: Optional[int] = None,
+                      trace=None) -> int:
+        """The fused scan with a per-(query, row) mask for the probe mask:
+        every bucket's centroid term on the host (``knn.probe``), the
+        allowed rows packed one bit a row and uploaded (``knn.filter``),
+        ONE ``pq_scan_rowmask`` dispatch over the whole code table, then
+        the exact re-rank of the candidates, which are all allowed rows."""
+        qn = queries.shape[0]
+        n_real = len(self.ids)
+        k_eff = min(k, n_real)
+        kprime = self._kprime(k_eff, n_real, rerank, rerank_mult)
+        residual = self.cfg.pq_residual
+        cterm = None
+        if residual:
+            with maybe_span(trace, "knn.probe"):
+                cterm = self._cterm_np(queries, _pairwise_scores_np(
+                    queries, self.centroids, self.cfg.metric))
+        with maybe_span(trace, "knn.filter") as sp:
+            rows = self._allowed_rows(allow)
+            mask = pack_row_mask(rows, n_real, self.cfg.block_n)
+            count_h2d("row_mask", sp, mask)
+            mask = jax.device_put(mask).block_until_ready()
+            if sp is not None:
+                sp.set(rows_allowed=int(sum(len(r) for r in rows)))
+        with maybe_span(trace, "knn.luts"):
+            luts = self._pq_luts(queries)                    # [Q, m, ksub]
+        with maybe_span(trace, "knn.scan") as sp:
+            vals, idx = pq_adc_topk(
+                luts, self.codes, kprime, block_n=self.cfg.block_n,
+                bias=(self.code_bias if residual else None),
+                row_bucket=(self.bucket_of if residual else None),
+                cscores=cterm, row_mask=mask, span=sp)
+        self._rerank_table(queries, vals, idx, k_eff, rerank, out_v, out_i,
+                           trace)
         return qn * n_real
+
+    def _scan_filtered_host(self, queries: np.ndarray,
+                            allow: Sequence[np.ndarray], k: int,
+                            out_v: np.ndarray, out_i: np.ndarray,
+                            trace=None) -> int:
+        """Exact scores of each query's allowed rows, compacted and
+        pending, in numpy; ties to the lower row."""
+        METRICS.counter("knn_filter_host_queries").inc(queries.shape[0])
+        with maybe_span(trace, "knn.filter") as sp:
+            rows = [np.unique(r) for r in self._allowed_rows(allow,
+                                                             pending=True)]
+            if sp is not None:
+                sp.set(rows_allowed=int(sum(len(r) for r in rows)))
+        with maybe_span(trace, "knn.host_scan"):
+            corpus, ids, _ = self._full_corpus()
+            for q, r in enumerate(rows):
+                if not len(r):
+                    continue
+                s = _exact_scores_np(queries[q:q + 1], corpus[r][None],
+                                     self.cfg.metric)[0]
+                top = _stable_topk_desc(s, min(k, len(r)))
+                out_v[q, :len(top)] = s[top]
+                out_i[q, :len(top)] = ids[r[top]]
+        return int(sum(len(r) for r in rows))
 
     def _scan_dense(self, queries: np.ndarray, probe: np.ndarray, k: int,
                     out_v: np.ndarray, out_i: np.ndarray,

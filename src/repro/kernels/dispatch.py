@@ -7,8 +7,9 @@ in ``KERNEL_MAX_K``; above it the jitted XLA twin runs instead.  The
 extended PQ scan ``pq_scan_ext`` takes the kernel up to k = 128, the widest
 at which it measured faster than its twin (one gather pass over a [Q, N]
 float32 score matrix per subspace) at every batch size of the SIFT cell on
-a TPU v5e (PERF.md); the other kernels keep 64, a bound set before any chip
-measurement.  Each decision is counted in ``METRICS`` (the ``pandadb``
+a TPU v5e (PERF.md), and so does its row-masked sibling
+``pq_scan_rowmask`` (the same network); the other kernels keep 64, a bound
+set before any chip measurement.  Each decision is counted in ``METRICS`` (the ``pandadb``
 namespace) as ``kernel_dispatch:<kernel>:pallas`` or ``:xla``;
 a Pallas call that runs in interpret mode also counts ``:interpret``, so a
 run on the chip can show which code executed and that none of it was
@@ -30,7 +31,7 @@ from jax import monitoring
 from repro.obs.metrics import MetricsRegistry
 
 KERNEL_MAX_K = {"ivf_scan": 64, "pq_scan": 64, "pq_scan_ext": 128,
-                "topk_merge": 64}
+                "pq_scan_rowmask": 128, "topk_merge": 64}
 
 METRICS = MetricsRegistry("pandadb")
 

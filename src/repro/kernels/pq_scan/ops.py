@@ -17,12 +17,15 @@ The optional ``bias`` / ``row_bucket`` / ``cscores`` / ``probe_mask``
 arguments carry the residual-PQ score decomposition and the fused
 whole-table scan (see ref.py); with ``probe_mask``, queries whose probed
 buckets hold fewer than k rows surface (val=-inf, id=-1) padding at the
-tail -- the same contract the shard merge already truncates.
+tail -- the same contract the shard merge already truncates.  ``row_mask``
+(filtered kNN) swaps the probe mask for a per-(query, row) allow bit,
+packed 32 queries to an int32 word (:func:`pack_row_mask`), and runs the
+row-masked kernel or its twin under a dispatch name of its own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +34,9 @@ import numpy as np
 from repro.kernels.dispatch import (count_h2d, impl_name, on_tpu, pad_rows,
                                     padded_queries, padded_rows, use_pallas)
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
-                                           pq_adc_topk_pallas)
+                                           pq_adc_topk_pallas,
+                                           pq_adc_topk_rowmask_pallas,
+                                           unpack_row_mask)
 
 _NEG_THRESH = -1.5e38   # kernel NEG mask values live below this
 
@@ -56,6 +61,23 @@ def _pq_topk_xla(luts: jnp.ndarray, codes: jnp.ndarray, n_valid: jnp.ndarray,
     return vals, idx.astype(jnp.int32)
 
 
+def _ext_scores_xla(luts, codes, bias, rb, cscores) -> jnp.ndarray:
+    """[Q, N] LUT gathers + bias + per-row bucket term."""
+    qn, m, _ksub = luts.shape
+    codes = codes.astype(jnp.int32)
+    s = jnp.zeros((qn, codes.shape[0]), jnp.float32)
+    for j in range(m):                      # static unroll: M is small
+        s = s + luts[:, j, :][:, codes[:, j]]
+    return s + bias[None, :] + cscores[:, rb]
+
+
+def _topk_valid(s, n_valid, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    cols = jnp.arange(s.shape[1])[None, :]
+    s = jnp.where(cols >= n_valid, -jnp.inf, s)
+    vals, idx = jax.lax.top_k(s, k)
+    return vals, idx.astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "masked"))
 def _pq_topk_xla_ext(luts: jnp.ndarray, codes: jnp.ndarray,
                      n_valid: jnp.ndarray, bias: jnp.ndarray,
@@ -64,24 +86,51 @@ def _pq_topk_xla_ext(luts: jnp.ndarray, codes: jnp.ndarray,
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Extended XLA twin: LUT gathers + bias + per-row bucket term (+ probe
     mask) + padding mask + top-k, one dispatch for the whole batch."""
-    qn, m, _ksub = luts.shape
-    codes = codes.astype(jnp.int32)
-    s = jnp.zeros((qn, codes.shape[0]), jnp.float32)
-    for j in range(m):                      # static unroll: M is small
-        s = s + luts[:, j, :][:, codes[:, j]]
     rb = row_bucket.astype(jnp.int32)
-    s = s + bias[None, :] + cscores[:, rb]
+    s = _ext_scores_xla(luts, codes, bias, rb, cscores)
     if masked:
         s = jnp.where(probe_mask[:, rb] > 0.5, s, -jnp.inf)
-    cols = jnp.arange(codes.shape[0])[None, :]
-    s = jnp.where(cols >= n_valid, -jnp.inf, s)
-    vals, idx = jax.lax.top_k(s, k)
-    return vals, idx.astype(jnp.int32)
+    return _topk_valid(s, n_valid, k)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _pq_topk_xla_rowmask(luts: jnp.ndarray, codes: jnp.ndarray,
+                         n_valid: jnp.ndarray, bias: jnp.ndarray,
+                         row_bucket: jnp.ndarray, cscores: jnp.ndarray,
+                         row_mask: jnp.ndarray, k: int
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Row-masked XLA twin: the extended scores with each query's rows
+    outside its filter (the packed ``row_mask``) pinned to -inf."""
+    rb = row_bucket.astype(jnp.int32)
+    s = _ext_scores_xla(luts, codes, bias, rb, cscores)
+    s = jnp.where(unpack_row_mask(row_mask, s.shape[0]), s, -jnp.inf)
+    return _topk_valid(s, n_valid, k)
+
+
+def pack_row_mask(rows: Sequence[np.ndarray], n: int,
+                  block_n: int = 512) -> np.ndarray:
+    """Per-query allowed row positions (each < ``n``; a repeated position
+    sets its bit once) -> the row-masked scan's packed allow bits,
+    [W, padded_rows(n)] int32 with W = ceil(padded_queries(Q) / 32): bit j
+    of word [w, r] says query 32*w + j may return row r.  Padded to the
+    shapes :func:`pq_adc_topk` pads the batch and code table to, so it can
+    be uploaded as is."""
+    qn = len(rows)
+    words = -(-padded_queries(qn) // 32)
+    cols = padded_rows(n, block_n)
+    counts = np.fromiter((len(r) for r in rows), np.int64, qn)
+    q = np.repeat(np.arange(qn), counts)
+    pos = (np.concatenate([np.asarray(r, np.int64) for r in rows])
+           if counts.sum() else np.zeros(0, np.int64))
+    packed = np.zeros(words * cols, np.uint32)
+    np.bitwise_or.at(packed, (q // 32) * cols + pos,
+                     np.left_shift(np.uint32(1), (q % 32).astype(np.uint32)))
+    return packed.view(np.int32).reshape(words, cols)
 
 
 def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
                 force_pallas: bool = False, bias=None, row_bucket=None,
-                cscores=None, probe_mask=None, span=None
+                cscores=None, probe_mask=None, row_mask=None, span=None
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """[Q, M, K] x [N, M] -> (vals [Q, k'], ids [Q, k']), k' = min(k, n_valid),
     as host arrays (inputs are padded on the host: pass host arrays).
@@ -91,8 +140,14 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     < ``n_valid``.  ``cscores`` / ``probe_mask`` require ``row_bucket``
     (see ref.py for the extended score decomposition); with ``probe_mask``,
     per-query positions past that query's probed row count come back as
-    (val=-inf, id=-1) padding.  The padded operands' bytes count as
-    ``h2d_bytes:pq_scan`` (``pq_scan_ext`` with any extended argument);
+    (val=-inf, id=-1) padding.  ``row_mask`` (:func:`pack_row_mask`'s
+    packed allow bits, already padded, on the host or the device) takes
+    the row-masked scan, ``pq_scan_rowmask``, instead: every bucket is
+    scored and each query sees only its allowed rows, with the same
+    (-inf, -1) tail where it has fewer than k'; it excludes
+    ``probe_mask``.  The padded operands' bytes count as
+    ``h2d_bytes:pq_scan`` (``pq_scan_ext`` or ``pq_scan_rowmask`` with any
+    extended argument; the row mask is the caller's to count);
     ``span`` (a live ``knn.scan`` span, or None) gets them with ``kernel``
     and ``impl``."""
     n = codes.shape[0]
@@ -103,10 +158,15 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     if k <= 0:
         return (np.zeros((qn, 0), np.float32),
                 np.zeros((qn, 0), np.int32))
-    ext = any(a is not None for a in (bias, row_bucket, cscores, probe_mask))
+    rowmask = row_mask is not None
+    ext = rowmask or any(a is not None
+                         for a in (bias, row_bucket, cscores, probe_mask))
     if (cscores is not None or probe_mask is not None) and row_bucket is None:
         raise ValueError("cscores/probe_mask require row_bucket")
-    kernel = "pq_scan_ext" if ext else "pq_scan"
+    if rowmask and probe_mask is not None:
+        raise ValueError("row_mask and probe_mask exclude each other")
+    kernel = ("pq_scan_rowmask" if rowmask
+              else "pq_scan_ext" if ext else "pq_scan")
     use_kernel = use_pallas(kernel, k, force_pallas)
     rows, qrows = padded_rows(n, block_n), padded_queries(qn)
     luts = pad_rows(luts, qrows, np.float32)
@@ -130,6 +190,20 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
                   np.int32)
     cs = pad_rows(np.zeros((qn, mb)) if cscores is None else cscores, qrows,
                   np.float32)
+    if rowmask:
+        if row_mask.shape != (-(-qrows // 32), rows):
+            raise ValueError(f"row_mask {row_mask.shape} is not packed for "
+                             f"{qrows} queries x {rows} rows")
+        count_h2d(kernel, span, luts, codes, bias, rb, cs, kernel=kernel,
+                  impl=impl_name(use_kernel))
+        if use_kernel:
+            v, i = pq_adc_topk_rowmask_pallas(
+                luts, codes, bias, rb, cs, row_mask, k, block_n=block_n,
+                n_valid=n_valid, interpret=not on_tpu())
+        else:
+            v, i = _pq_topk_xla_rowmask(luts, codes, np.int32(n_valid), bias,
+                                        rb, cs, row_mask, k)
+        return _empty_tail(v, i, qn)
     pm = pad_rows(np.ones((qn, mb)) if probe_mask is None else probe_mask,
                   qrows, np.float32)
     count_h2d(kernel, span, luts, codes, bias, rb, cs, pm, kernel=kernel,
@@ -141,10 +215,14 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     else:
         v, i = _pq_topk_xla_ext(luts, codes, np.int32(n_valid), bias, rb,
                                 cs, pm, k, masked)
-    v, i = np.asarray(v)[:qn], np.asarray(i)[:qn]
     if masked:
-        # the kernel's in-kernel NEG mask stands in for -inf: restore it and
-        # pin the id payload of empty positions to -1 (the merge contract)
-        v = np.where(v <= _NEG_THRESH, -np.inf, v).astype(np.float32)
-        i = np.where(np.isfinite(v), i, -1).astype(np.int32)
-    return v, i
+        return _empty_tail(v, i, qn)
+    return np.asarray(v)[:qn], np.asarray(i)[:qn]
+
+
+def _empty_tail(v, i, qn: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The kernel's in-kernel NEG mask stands in for -inf: restore it and
+    pin the id payload of empty positions to -1 (the merge contract)."""
+    v, i = np.asarray(v)[:qn], np.asarray(i)[:qn]
+    v = np.where(v <= _NEG_THRESH, -np.inf, v).astype(np.float32)
+    return v, np.where(np.isfinite(v), i, -1).astype(np.int32)

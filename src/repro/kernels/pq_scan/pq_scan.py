@@ -21,6 +21,12 @@ tile's bucket ids contracts against a 128-bucket block of ``cscores`` /
 by bucket -- as a compacted index is -- pays for one or two blocks per tile
 instead of the whole bucket axis, and an unsorted one is still exact.
 
+The *row-masked* kernel (filtered kNN) keeps the LUT sum, bias and
+centroid term and replaces the probe mask by a per-(query, row) allow bit:
+each tile reads its [ceil(Q/32), BN] slice of a packed int32 mask, spreads
+every word over 32 sublanes with a shift, and pins the rows a query's
+filter excludes to ``NEG`` before the top-L.
+
 Precision.  Scores are float32 sums, as the XLA twin's gathers give: the
 one-hot is exact in bfloat16, and every float operand (LUTs, centroid
 terms) is split on the way in into three bfloat16 pieces whose sum is the
@@ -89,13 +95,12 @@ def _pq_kernel(nv_ref, luts_ref, codes_ref, vals_ref, idx_ref, *, topl: int,
     tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
 
 
-def _pq_kernel_ext(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref, bias_ref,
-                   rb_ref, bt_ref, vals_ref, idx_ref, acc_ref, *, topl: int,
-                   block_n: int, ksub: int, qp: int):
-    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
-    # bucket terms: for each 128-bucket block this tile's rows fall in,
-    # one-hot the rows' bucket ids and contract the block's centroid-term
-    # pieces and probe mask [4Q, 128] against it
+def _bucket_terms(blo_ref, bhi_ref, rb_ref, bt_ref, acc_ref,
+                  block_n: int) -> jnp.ndarray:
+    """For each 128-bucket block this tile's rows fall in, one-hot the
+    rows' bucket ids and contract the block's bucket rows [R, 128]
+    (centroid-term pieces, and the probe mask where there is one) against
+    it: [R, BN] float32."""
     rb = rb_ref[...]                                       # [1, BN] int32
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
@@ -109,10 +114,41 @@ def _pq_kernel_ext(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref, bias_ref,
 
     i = pl.program_id(0)
     jax.lax.fori_loop(blo_ref[i], bhi_ref[i] + 1, block, 0)
-    acc = acc_ref[...]
+    return acc_ref[...]
+
+
+def _pq_kernel_ext(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref, bias_ref,
+                   rb_ref, bt_ref, vals_ref, idx_ref, acc_ref, *, topl: int,
+                   block_n: int, ksub: int, qp: int):
+    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
+    acc = _bucket_terms(blo_ref, bhi_ref, rb_ref, bt_ref, acc_ref, block_n)
     cterm = (acc[2 * qp:3 * qp] + acc[qp:2 * qp]) + acc[:qp]
     s = (s + bias_ref[...]) + cterm                       # bias tile [1, BN]
     s = jnp.where(acc[3 * qp:] > 0.5, s, NEG)
+    tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
+
+
+def unpack_row_mask(words: jnp.ndarray, qn: int) -> jnp.ndarray:
+    """[W, C] int32 words -> [qn, C] bool: bit j of word [w, c] is query
+    32*w + j's allow bit for column c (the layout of ``ops.pack_row_mask``).
+    Each word row is broadcast over 32 sublanes and shifted by their index,
+    so no lane ever moves."""
+    c = words.shape[1]
+    shift = jax.lax.broadcasted_iota(jnp.int32, (32, c), 0)
+    bits = jnp.concatenate([(words[w:w + 1] >> shift) & 1
+                            for w in range(words.shape[0])])
+    return bits[:qn] != 0
+
+
+def _pq_kernel_rowmask(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref,
+                       bias_ref, rb_ref, bt_ref, mask_ref, vals_ref, idx_ref,
+                       acc_ref, *, topl: int, block_n: int, ksub: int,
+                       qp: int):
+    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
+    acc = _bucket_terms(blo_ref, bhi_ref, rb_ref, bt_ref, acc_ref, block_n)
+    cterm = (acc[2 * qp:3 * qp] + acc[qp:2 * qp]) + acc[:qp]
+    s = (s + bias_ref[...]) + cterm                       # bias tile [1, BN]
+    s = jnp.where(unpack_row_mask(mask_ref[...], qp), s, NEG)
     tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
 
 
@@ -181,34 +217,30 @@ def pq_adc_topk_pallas(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
     return merge_tiles(vals[:, :qn], idx[:, :qn], k)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
-def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
-                           bias: jnp.ndarray, row_bucket: jnp.ndarray,
-                           cscores: jnp.ndarray, probe_mask: jnp.ndarray,
-                           k: int, block_n: int = 512, n_valid=-1,
-                           interpret: bool = True
-                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Extended ADC scan: LUT sum + bias[n] + cscores[q, row_bucket[n]],
-    rows of non-probed buckets (probe_mask False) pinned to ``NEG``.
-    Shapes: luts [Q, M, K], codes [N, M], bias [N], row_bucket [N] in
-    [0, MB), cscores/probe_mask [Q, MB]; N % block_n == 0."""
+def _bucket_scan(kernel, luts, codes, bias, row_bucket, cscores, masks,
+                 tiled, k: int, block_n: int, n_valid, interpret: bool):
+    """The extended scans' shared pallas_call: LUT pieces, code / bias /
+    bucket tiles and the bucket operand -- the centroid-term pieces
+    followed by ``masks`` ([R, MB] rows per query: the probe mask, or
+    none) cut into 128-bucket blocks -- then ``tiled``, further [rows, N]
+    operands tiled along the rows (the packed row mask, or none)."""
     qn, m, ksub = luts.shape
     n = codes.shape[0]
     mb = cscores.shape[1]
-    assert probe_mask.shape == cscores.shape, (probe_mask.shape,
-                                               cscores.shape)
     qp, luts3, codes_t = _prep(luts, codes, block_n)
     n_tiles = n // block_n
     nblk = -(-mb // BUCKET_BLOCK)
     out_specs, out_shape = tile_outputs(n_tiles, qp, k)
 
-    # bucket operand: (cscores hi; mid; lo; probe mask) rows, cut into
-    # 128-bucket blocks [nblk, 4Q, 128] so a block is a leading-axis index
+    # bucket operand: (cscores hi; mid; lo; masks) rows, cut into 128-bucket
+    # blocks [nblk, R*Q, 128] so a block is a leading-axis index
     pad = ((0, qp - qn), (0, nblk * BUCKET_BLOCK - mb))
-    bt = jnp.concatenate([
-        split3(jnp.pad(cscores.astype(jnp.float32), pad)),
-        jnp.pad(probe_mask.astype(jnp.float32), pad).astype(jnp.bfloat16)])
-    bt = bt.reshape(4 * qp, nblk, BUCKET_BLOCK).transpose(1, 0, 2)
+    bt = jnp.concatenate(
+        [split3(jnp.pad(cscores.astype(jnp.float32), pad))]
+        + [jnp.pad(x.astype(jnp.float32), pad).astype(jnp.bfloat16)
+           for x in masks])
+    rows = bt.shape[0]
+    bt = bt.reshape(rows, nblk, BUCKET_BLOCK).transpose(1, 0, 2)
     # each tile's range of bucket blocks over its real rows; an all-padding
     # tile gets an empty range
     nv = n_valid_operand(n_valid, n)
@@ -219,8 +251,8 @@ def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
     blo = jnp.clip(blo // BUCKET_BLOCK, 0, nblk)
     bhi = jnp.clip(bhi // BUCKET_BLOCK, -1, nblk - 1)
 
-    kernel = functools.partial(_pq_kernel_ext, topl=k, block_n=block_n,
-                               ksub=ksub, qp=qp)
+    kernel = functools.partial(kernel, topl=k, block_n=block_n, ksub=ksub,
+                               qp=qp)
     tile = lambda i, *_: (0, i)                                  # noqa: E731
     # bias and row_bucket ride as [1, N] rows: 1-D tiles of block_n lanes
     # have no TPU layout XLA and Mosaic agree on, and an [N, 1] column
@@ -236,14 +268,56 @@ def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
                 pl.BlockSpec((1, block_n), tile),        # bias tile
                 pl.BlockSpec((1, block_n), tile),        # bucket tile
                 pl.BlockSpec(memory_space=pltpu.VMEM),   # bucket blocks
-            ],
+            ] + [pl.BlockSpec((x.shape[0], block_n), tile) for x in tiled],
             out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((4 * qp, block_n), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((rows, block_n), jnp.float32)]),
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(qp, m * ksub, block_n, k,
                                          bucket_bytes=bt.size * 2)),
         interpret=interpret,
     )(nv, blo.astype(jnp.int32), bhi.astype(jnp.int32), luts3, codes_t,
-      bias.astype(jnp.float32)[None, :], rb[None, :], bt)
+      bias.astype(jnp.float32)[None, :], rb[None, :], bt, *tiled)
     return merge_tiles(vals[:, :qn], idx[:, :qn], k)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+def pq_adc_topk_ext_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
+                           bias: jnp.ndarray, row_bucket: jnp.ndarray,
+                           cscores: jnp.ndarray, probe_mask: jnp.ndarray,
+                           k: int, block_n: int = 512, n_valid=-1,
+                           interpret: bool = True
+                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Extended ADC scan: LUT sum + bias[n] + cscores[q, row_bucket[n]],
+    rows of non-probed buckets (probe_mask False) pinned to ``NEG``.
+    Shapes: luts [Q, M, K], codes [N, M], bias [N], row_bucket [N] in
+    [0, MB), cscores/probe_mask [Q, MB]; N % block_n == 0."""
+    assert probe_mask.shape == cscores.shape, (probe_mask.shape,
+                                               cscores.shape)
+    return _bucket_scan(_pq_kernel_ext, luts, codes, bias, row_bucket,
+                        cscores, [probe_mask], [], k, block_n, n_valid,
+                        interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
+def pq_adc_topk_rowmask_pallas(luts: jnp.ndarray, codes: jnp.ndarray,
+                               bias: jnp.ndarray, row_bucket: jnp.ndarray,
+                               cscores: jnp.ndarray, row_mask: jnp.ndarray,
+                               k: int, block_n: int = 512, n_valid=-1,
+                               interpret: bool = True
+                               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Row-masked ADC scan (filtered kNN): LUT sum + bias[n] +
+    cscores[q, row_bucket[n]] over every bucket, each query's rows outside
+    its filter pinned to ``NEG`` before the tile top-L.  ``row_mask`` is
+    the packed allow bits [ceil(Q/32), N] int32 of ``ops.pack_row_mask``
+    (bit j of word [w, n]: query 32*w + j may return row n).  Shapes
+    otherwise as :func:`pq_adc_topk_ext_pallas`, without a probe mask."""
+    qp = -(-luts.shape[0] // _Q_ALIGN) * _Q_ALIGN
+    words = -(-qp // 32)
+    assert row_mask.shape[1] == codes.shape[0], (row_mask.shape,
+                                                 codes.shape)
+    row_mask = jnp.pad(row_mask.astype(jnp.int32),
+                       ((0, words - row_mask.shape[0]), (0, 0)))
+    return _bucket_scan(_pq_kernel_rowmask, luts, codes, bias, row_bucket,
+                        cscores, [], [row_mask], k, block_n, n_valid,
+                        interpret)

@@ -20,6 +20,10 @@ term, masked to the probed buckets):
   query did not probe to -inf (the fused path scans the whole code table
   in one call instead of gathering per signature).
 
+``row_mask [Q, N]`` (bool, filtered kNN) pins each row a query's filter
+excludes to -inf; the kernel takes the same bits packed 32 queries to a
+word (``ops.pack_row_mask``).
+
 Tie-breaking matches ``jax.lax.top_k`` (equal scores -> lower row index),
 so candidate ids are byte-comparable against the kernel and the XLA twin.
 """
@@ -31,9 +35,10 @@ import numpy as np
 
 
 def pq_scores_ref(luts, codes, bias=None, row_bucket=None, cscores=None,
-                  probe_mask=None) -> np.ndarray:
+                  probe_mask=None, row_mask=None) -> np.ndarray:
     """[Q, M, K] x [N, M] -> [Q, N]: s[q, n] = sum_m luts[q, m, codes[n, m]]
-    (+ bias[n] + cscores[q, row_bucket[n]], non-probed buckets -> -inf)."""
+    (+ bias[n] + cscores[q, row_bucket[n]], non-probed buckets and rows
+    outside ``row_mask`` -> -inf)."""
     luts = np.asarray(luts, np.float32)
     codes = np.asarray(codes).astype(np.int64)
     q, m, _k = luts.shape
@@ -48,25 +53,29 @@ def pq_scores_ref(luts, codes, bias=None, row_bucket=None, cscores=None,
             s += np.asarray(cscores, np.float32)[:, rb]
         if probe_mask is not None:
             s = np.where(np.asarray(probe_mask, bool)[:, rb], s, -np.inf)
+    if row_mask is not None:
+        s = np.where(np.asarray(row_mask, bool), s, -np.inf)
     return s
 
 
 def pq_adc_topk_ref(luts, codes, k: int, n_valid: int = -1, bias=None,
-                    row_bucket=None, cscores=None, probe_mask=None
-                    ) -> Tuple[np.ndarray, np.ndarray]:
+                    row_bucket=None, cscores=None, probe_mask=None,
+                    row_mask=None) -> Tuple[np.ndarray, np.ndarray]:
     """[Q, M, K] x [N, M] -> (scores [Q, k], indices [Q, k]), higher = better.
 
     ``n_valid`` (< N) masks trailing padding rows to -inf, mirroring the
     kernel's contract so the dispatcher can pad code tables freely."""
     s = pq_scores_ref(luts, codes, bias=bias, row_bucket=row_bucket,
-                      cscores=cscores, probe_mask=probe_mask)
+                      cscores=cscores, probe_mask=probe_mask,
+                      row_mask=row_mask)
     n = s.shape[1]
     if 0 <= n_valid < n:
         s[:, n_valid:] = -np.inf
     # stable descending sort == lax.top_k tie order (lower index first)
     idx = np.argsort(-s, axis=1, kind="stable")[:, :k]
     vals = np.take_along_axis(s, idx, axis=1)
-    if probe_mask is not None:
-        # a query probing fewer than k rows pads its tail: (val=-inf, id=-1)
+    if probe_mask is not None or row_mask is not None:
+        # a query probing or allowed fewer than k rows pads its tail:
+        # (val=-inf, id=-1)
         idx = np.where(np.isfinite(vals), idx, -1)
     return vals.astype(np.float32), idx.astype(np.int32)

@@ -20,7 +20,8 @@ import pytest
 
 from repro.kernels.ivf_scan.ivf_scan import ivf_scan_topk_pallas
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
-                                           pq_adc_topk_pallas)
+                                           pq_adc_topk_pallas,
+                                           pq_adc_topk_rowmask_pallas)
 from repro.kernels.topk_merge.topk_merge import merge_topk_pallas
 
 N, D, M, KSUB, MB = 131072, 128, 16, 256, 128   # corpus rows, dim, PQ, buckets
@@ -106,6 +107,22 @@ def test_pq_scan_ext_compiles_at_served_shape(one_chip):
     args = (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
             _spec((N,), f32, sh), _spec((N,), i32, sh),
             _spec((qn, mb), f32, sh), _spec((qn, mb), f32, sh),
+            _spec((), i32, sh))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("qn", [32, 256])
+def test_pq_scan_rowmask_compiles_at_served_shape(one_chip, qn):
+    """The row-masked ADC scan at the filtered cell's widths: M 24, K 256,
+    N 131 072, 1 420 buckets, k' 80, the packed mask [Q/32, N] int32."""
+    f32, i32, u8, sh = jnp.float32, jnp.int32, jnp.uint8, one_chip
+    m, mb, k = 24, 1420, 80
+    fn = lambda l, c, b, rb, cs, rm, nv: pq_adc_topk_rowmask_pallas(  # noqa: E731
+        l, c, b, rb, cs, rm, k, n_valid=nv, interpret=False)
+    args = (_spec((qn, m, KSUB), f32, sh), _spec((N, m), u8, sh),
+            _spec((N,), f32, sh), _spec((N,), i32, sh),
+            _spec((qn, mb), f32, sh), _spec((qn // 32, N), i32, sh),
             _spec((), i32, sh))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
