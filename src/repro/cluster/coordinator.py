@@ -730,10 +730,11 @@ class ShardedPandaDB:
 
     def _resolve_filters(self, filter_key: str,
                          filters: Sequence[Sequence[str]]
-                         ) -> List[List[np.ndarray]]:
-        """Per shard, each query's allowed blob ids: the AND of its tokens
-        over the shard's inverted index on ``filter_key`` (a shard with no
-        such index allows nothing)."""
+                         ) -> List[List[tuple]]:
+        """Per shard, each query's filter: the tuple of its tokens'
+        postings on the shard's inverted index over ``filter_key``, which
+        the shard's index piece ANDs as row bitmaps (a shard with no such
+        index allows nothing)."""
         if not filter_key:
             raise ValueError("filters need a filter_key")
         if any(len(f) == 0 for f in filters):
@@ -742,9 +743,10 @@ class ShardedPandaDB:
                 for s in self.active]
         if all(ix is None for ix in idxs):
             raise KeyError(f"no scalar index on {filter_key!r}")
-        none = np.zeros(0, np.int64)
+        none = (np.zeros(0, np.int64),)
         return [[none] * len(filters) if ix is None
-                else [ix.lookup_all(f) for f in filters] for ix in idxs]
+                else [tuple(map(ix.posting, f)) for f in filters]
+                for ix in idxs]
 
     # -- kNN scatter-gather -----------------------------------------------------
 
@@ -768,8 +770,9 @@ class ShardedPandaDB:
         tokens over the scalar index ``filter_key`` (an inverted index from
         :meth:`build_scalar_index`, e.g. a photo's tags): the query is
         answered from the blobs that carry every token, and may return
-        fewer than ``k``.  Each shard resolves the tokens on its own index
-        (``knn.filter`` span) and scans its own allowed rows."""
+        fewer than ``k``.  Each shard hands its index piece the tokens'
+        postings on its own inverted index (``knn.filter`` span); the
+        piece ANDs them as row bitmaps and scans only the rows that pass."""
         deadline = Deadline.resolve(deadline_ms)
         own_trace = trace is None and self.tracer.enabled
         if own_trace:

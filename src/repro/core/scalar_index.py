@@ -17,6 +17,7 @@ update, exactly like the vector index.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from collections import defaultdict
 from typing import Dict, Iterable, List, Optional, Sequence
 
@@ -57,10 +58,31 @@ class NumericIndex:
         self.ids = np.insert(self.ids, pos, item_id)
 
 
+_INDEX_UIDS = itertools.count(1)
+_NO_IDS = np.zeros(0, np.int64)
+_NO_IDS.flags.writeable = False
+
+
+@dataclasses.dataclass(eq=False)
+class Posting:
+    """One token's posting: its sorted item ``ids``, the ``token``, and
+    the ``owner`` (index uid, index generation) under which a consumer
+    may cache what it derives from them.  An owner and a token name one
+    content for ever: an :meth:`InvertedIndex.insert` moves the index's
+    generation, and a rebuilt index has a uid of its own."""
+    ids: np.ndarray
+    owner: tuple
+    token: str
+
+
 @dataclasses.dataclass
 class InvertedIndex:
     postings: Dict[str, np.ndarray]
     serial: int = 1
+    uid: int = dataclasses.field(default_factory=lambda: next(_INDEX_UIDS),
+                                 init=False, compare=False)
+    generation: int = dataclasses.field(default=0, init=False,
+                                        compare=False)
 
     @staticmethod
     def build(tokens_per_item: Sequence[Iterable[str]], ids: Sequence[int],
@@ -78,20 +100,14 @@ class InvertedIndex:
     def lookup(self, token: str) -> np.ndarray:
         return self.postings.get(str(token).lower(), np.array([], np.int64))
 
-    def lookup_all(self, tokens: Iterable[str]) -> np.ndarray:
-        """AND-semantics posting intersection, sorted.  The shortest list
-        leads, and each further list is binary-searched for its
-        survivors: O(shortest x log longest), not a sort of both."""
-        lists = sorted((self.lookup(t) for t in tokens), key=len)
-        if not lists:
-            return np.array([], np.int64)
-        out = lists[0]
-        for p in lists[1:]:
-            if not len(out):
-                break
-            j = np.minimum(np.searchsorted(p, out), len(p) - 1)
-            out = out[p[j] == out] if len(p) else out[:0]
-        return out
+    def posting(self, token: str) -> Posting:
+        """``token``'s posting with its owner.  The generation is read
+        before the ids (``insert`` writes them in the other order), so an
+        owner never names ids older than its generation."""
+        t = str(token).lower()
+        owner = (self.uid, self.generation)
+        ids = self.postings.get(t)
+        return Posting(_NO_IDS if ids is None else ids, owner, t)
 
     def insert(self, tokens: Iterable[str], item_id: int) -> None:
         if isinstance(tokens, str):
@@ -100,6 +116,7 @@ class InvertedIndex:
             t = str(t).lower()
             p = self.postings.get(t, np.array([], np.int64))
             self.postings[t] = np.unique(np.append(p, item_id))
+        self.generation += 1
 
     @property
     def vocabulary(self) -> List[str]:

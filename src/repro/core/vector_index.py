@@ -44,9 +44,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.pandadb import VectorIndexConfig
-from repro.kernels.dispatch import METRICS, count_h2d
+from repro.core.scalar_index import Posting
+from repro.kernels.dispatch import (METRICS, count_h2d, padded_queries,
+                                    padded_rows)
 from repro.kernels.ivf_scan.ops import ivf_scan_topk
-from repro.kernels.pq_scan.ops import pack_row_mask, pq_adc_topk
+from repro.kernels.pq_scan.ops import pq_adc_topk, row_mask_from_bitmaps
 from repro.kernels.topk_merge.ops import merge_topk_dev
 from repro.obs.trace import maybe_span
 
@@ -203,9 +205,10 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     a ``degradation`` event when the partial top-k ladder step fires.
 
     ``allow`` (filtered kNN, optional) holds, per shard, each query's
-    allowed external ids (the shard's share of the filter, resolved by
-    the caller): every shard answers each query from those rows only
-    (:meth:`IVFIndex.search_many`), and the merge is unchanged."""
+    filter over that shard's rows (allowed external ids, or a tuple of the
+    shard's postings to AND; :meth:`IVFIndex.search_many`): every shard
+    answers each query from the rows that pass it only, and the merge is
+    unchanged."""
     queries = np.asarray(queries, np.float32)
     qn = queries.shape[0]
     out_v = np.full((qn, k), -np.inf, np.float32)
@@ -478,6 +481,26 @@ def _residual_bias(pq: PQCodebook, codes: np.ndarray, centroids: np.ndarray,
             - np.einsum("nd,nd->n", r, r)).astype(np.float32)
 
 
+def _terms(entry) -> tuple:
+    """A query's filter as its terms: a tuple of terms ANDs them; a lone
+    posting or array of ids is the one-term filter.  A tuple whose items
+    are not terms (say, a tuple of ids) is refused, not read as terms."""
+    if not isinstance(entry, tuple):
+        return (entry,)
+    if not entry:
+        raise ValueError("a filter names at least one term")
+    if not all(isinstance(t, (Posting, np.ndarray)) for t in entry):
+        raise TypeError("a tuple filter ANDs postings or id arrays; give "
+                        "one term's ids as an array")
+    return entry
+
+
+def _term_ids(term) -> np.ndarray:
+    """The external ids a filter term (a posting or ids) allows."""
+    ids = term.ids if isinstance(term, Posting) else term
+    return np.asarray(ids, np.int64).reshape(-1)
+
+
 # ---------------------------------------------------------------------------
 # IVF-Flat / IVF-PQ
 # ---------------------------------------------------------------------------
@@ -514,9 +537,17 @@ class IVFIndex:
     pending_count: int = 0
     # rows scanned so far (the coordinator's per-shard throughput feed)
     scan_rows: int = 0
+    # compactions so far: the compacted rows' layout, which a filter
+    # bitmap cached for it (:meth:`_filter_words`) is keyed by
+    generation: int = dataclasses.field(default=0, init=False,
+                                        compare=False)
     # external id -> compacted row lookup of filtered searches, built on
     # the first one after a build or compaction (:meth:`_id_rows`)
     _row_lookup: Optional[tuple] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
+    # ((generation, (index uid, index generation)), {token: bitmap}): the
+    # row bitmaps of long postings of one inverted index's generation
+    _bitmaps: Optional[tuple] = dataclasses.field(
         default=None, init=False, repr=False, compare=False)
 
     @property
@@ -692,6 +723,7 @@ class IVFIndex:
         self._pend_bias.clear()
         self.pending_count = 0
         self._row_lookup = None
+        self.generation += 1
 
     # -- kNN search -------------------------------------------------------------
 
@@ -800,9 +832,11 @@ class IVFIndex:
         single-query host path.  Spans that hand host arrays to a device
         program carry their bytes as ``h2d_bytes``.
 
-        ``allow`` (filtered kNN) gives each query its allowed external ids
-        (a repeated id counts once): a query is answered from those rows
-        only, so it may return fewer
+        ``allow`` (filtered kNN) gives each query its filter: one term or
+        a tuple of terms, ANDed, each term an array of allowed external
+        ids (a repeated id counts once) or a
+        :class:`~repro.core.scalar_index.Posting`.  A query is answered
+        from the rows that pass its filter only, so it may return fewer
         than ``k`` (the tail is -inf / -1) and never another row.  A
         filtered query probes every bucket, the filter being its only mask
         (``nprobe`` does not apply).  See :meth:`_search_filtered` for the
@@ -1216,8 +1250,9 @@ class IVFIndex:
                          stats, out_v: np.ndarray, out_i: np.ndarray,
                          rerank: bool, rerank_mult: Optional[int],
                          trace=None) -> None:
-        """Filtered kNN over every bucket, each query restricted to its
-        ``allow`` ids.  A batch on a compacted PQ index takes ONE
+        """Filtered kNN over every bucket, each query restricted to the
+        rows its ``allow`` filter passes.  A batch on a compacted PQ index
+        takes ONE
         row-masked ADC dispatch over the whole code table
         (:meth:`_scan_rowmask`), then the exact re-rank.  One query,
         pending appends, a flat index or ``mode="float"`` take the host
@@ -1268,31 +1303,130 @@ class IVFIndex:
         j = np.minimum(np.searchsorted(keys, ext_ids), len(keys) - 1)
         return np.where(keys[j] == ext_ids, order[j], -1)
 
-    def _allowed_rows(self, allow: Sequence[np.ndarray],
-                      pending: bool = False) -> List[np.ndarray]:
-        """Each query's allowed ids -> its row positions: compacted table
-        rows, then (``pending``) appended rows numbered after them in
-        :meth:`_full_corpus` order.  Ids with no row here are dropped."""
-        counts = np.fromiter((len(a) for a in allow), np.int64, len(allow))
-        flat = (np.concatenate([np.asarray(a, np.int64) for a in allow])
-                if counts.sum() else np.zeros(0, np.int64))
-        pos = self._id_rows(flat)
+    def _filter_words(self, allow: Sequence, qrows: Optional[int] = None
+                      ) -> Tuple[np.ndarray, int, int]:
+        """Each query's filter as a bitmap over the compacted rows:
+        ([qrows or Q, padded_rows(n) / 32] uint32, cached, built), bit
+        r % 32 of word r // 32 set where the query may return row r; rows
+        past the queries, and bits past the last real row, are zero.
+
+        A query's filter is one term or a tuple of terms, ANDed.  A term is
+        a :class:`~repro.core.scalar_index.Posting` or an array of external
+        ids; ids with no compacted row drop out.  A batch builds each
+        distinct term's bitmap once.  A posting of at least
+        padded_rows / 64 ids, whose bitmap is then no larger than the
+        posting, keeps it in a cache keyed by this index's generation and
+        the posting's key, so a compaction or an index insert never serves
+        a stale one; shorter terms are built per call.  ``cached`` and
+        ``built`` count the distinct terms each way, also as the
+        ``knn_filter_bitmap:cached`` / ``:built`` counters."""
+        rows = padded_rows(len(self.ids), self.cfg.block_n)
+        w = rows // 32
+        slot_of: Dict[object, int] = {}
+        slots: List[object] = []
+        index: List[List[int]] = []
+        for entry in allow:
+            mine = []
+            for t in _terms(entry):
+                key = ((t.owner, t.token) if isinstance(t, Posting)
+                       else id(t))
+                if key not in slot_of:
+                    slot_of[key] = len(slots)
+                    slots.append(t)
+                mine.append(slot_of[key])
+            index.append(mine)
+        table = np.empty((len(slots) + 1, w), np.uint32)
+        table[-1] = np.uint32(0xFFFFFFFF)        # pads a shorter AND
+        small: List[int] = []
+        cached = built = 0
+        for j, t in enumerate(slots):
+            if not isinstance(t, Posting) or len(t.ids) * 64 < rows:
+                small.append(j)
+                continue
+            cache = self._bitmap_cache(t.owner)
+            bm = cache.get(t.token)
+            if bm is None:
+                bm = cache[t.token] = self._term_bitmaps([t], w)[0]
+                built += 1
+            else:
+                cached += 1
+            table[j] = bm
+        if small:
+            table[small] = self._term_bitmaps([slots[j] for j in small], w)
+            built += len(small)
+        METRICS.counter("knn_filter_bitmap:cached").inc(cached)
+        METRICS.counter("knn_filter_bitmap:built").inc(built)
+        depth = max(map(len, index), default=1)
+        cols = np.full((len(index), depth), len(slots), np.int64)
+        for q, m in enumerate(index):
+            cols[q, :len(m)] = m
+        out = np.zeros((qrows or len(index), w), np.uint32)
+        np.take(table, cols[:, 0], axis=0, out=out[:len(index)])
+        for d in range(1, depth):
+            sel = np.flatnonzero(cols[:, d] < len(slots))
+            out[sel] &= table[cols[sel, d]]
+        return out, cached, built
+
+    def _bitmap_cache(self, owner: tuple) -> dict:
+        """token -> cached row bitmap, for postings of ``owner`` (an
+        inverted index and its generation) on this index's current rows;
+        a posting of another index, generation or row layout starts a new
+        cache, so the cache holds one inverted index's long postings at
+        most."""
+        key = (self.generation, owner)
+        held = self._bitmaps
+        if held is None or held[0] != key:
+            held = self._bitmaps = (key, {})
+        return held[1]
+
+    def _term_bitmaps(self, terms: Sequence, w: int) -> np.ndarray:
+        """[len(terms), w] uint32 row bitmaps of ``terms`` (postings or id
+        arrays), from one id -> row lookup and one scatter."""
+        ids = [_term_ids(t) for t in terms]
+        counts = np.fromiter((len(a) for a in ids), np.int64, len(ids))
+        out = np.zeros(len(ids) * w, np.uint32)
+        if counts.sum():
+            pos = self._id_rows(np.concatenate(ids))
+            term = np.repeat(np.arange(len(ids)), counts)
+            hit = pos >= 0
+            pos, term = pos[hit], term[hit]
+            np.bitwise_or.at(out, term * w + (pos >> 5),
+                             np.left_shift(np.uint32(1),
+                                           (pos & 31).astype(np.uint32)))
+        return out.reshape(len(ids), w)
+
+    def _allowed_rows(self, allow: Sequence, pending: bool = False
+                      ) -> Tuple[List[np.ndarray], int, int]:
+        """Each query's allowed row positions, sorted, for the host path:
+        the compacted rows of its bitmap (:meth:`_filter_words`), then
+        (``pending``) the appended rows, numbered after them in
+        :meth:`_full_corpus` order, whose id passes every term and has no
+        compacted row.  Returns (rows, cached, built)."""
+        words, cached, built = self._filter_words(allow)
+        n = len(self.ids)
+        bits = np.unpackbits(words.astype("<u4").view(np.uint8), axis=1,
+                             count=n, bitorder="little")
+        rows = [np.flatnonzero(b) for b in bits]
         if pending and self.pending_count:
             pend = np.asarray([i for b in sorted(self._pend_ids)
                                for i in self._pend_ids[b]], np.int64)
-            order = np.argsort(pend, kind="stable")
-            j = np.minimum(np.searchsorted(pend[order], flat), len(pend) - 1)
-            hit = (pos < 0) & (pend[order][j] == flat)
-            pos = np.where(hit, len(self.ids) + order[j], pos)
-        return [p[p >= 0] for p in np.split(pos, np.cumsum(counts)[:-1])]
+            uniq, first = np.unique(pend, return_index=True)
+            keep = np.sort(first[self._id_rows(uniq) < 0])
+            for q, entry in enumerate(allow):
+                ok = np.ones(len(keep), bool)
+                for t in _terms(entry):
+                    ok &= np.isin(pend[keep], _term_ids(t))
+                rows[q] = np.concatenate([rows[q], n + keep[ok]])
+        return rows, cached, built
 
-    def _scan_rowmask(self, queries: np.ndarray, allow: Sequence[np.ndarray],
+    def _scan_rowmask(self, queries: np.ndarray, allow: Sequence,
                       k: int, out_v: np.ndarray, out_i: np.ndarray,
                       rerank: bool, rerank_mult: Optional[int] = None,
                       trace=None) -> int:
         """The fused scan with a per-(query, row) mask for the probe mask:
-        every bucket's centroid term on the host (``knn.probe``), the
-        allowed rows packed one bit a row and uploaded (``knn.filter``),
+        every bucket's centroid term on the host (``knn.probe``), each
+        query's row bitmap (:meth:`_filter_words`) uploaded and transposed
+        into the kernel's packed layout on the device (``knn.filter``),
         ONE ``pq_scan_rowmask`` dispatch over the whole code table, then
         the exact re-rank of the candidates, which are all allowed rows."""
         qn = queries.shape[0]
@@ -1306,12 +1440,14 @@ class IVFIndex:
                 cterm = self._cterm_np(queries, _pairwise_scores_np(
                     queries, self.centroids, self.cfg.metric))
         with maybe_span(trace, "knn.filter") as sp:
-            rows = self._allowed_rows(allow)
-            mask = pack_row_mask(rows, n_real, self.cfg.block_n)
-            count_h2d("row_mask", sp, mask)
-            mask = jax.device_put(mask).block_until_ready()
+            words, cached, built = self._filter_words(allow,
+                                                      padded_queries(qn))
+            count_h2d("row_mask", sp, words)
+            mask = row_mask_from_bitmaps(words)
             if sp is not None:
-                sp.set(rows_allowed=int(sum(len(r) for r in rows)))
+                sp.set(rows_allowed=int(np.bitwise_count(
+                           words.view(np.uint64)).sum()),
+                       bitmaps_cached=cached, bitmaps_built=built)
         with maybe_span(trace, "knn.luts"):
             luts = self._pq_luts(queries)                    # [Q, m, ksub]
         with maybe_span(trace, "knn.scan") as sp:
@@ -1324,18 +1460,17 @@ class IVFIndex:
                            trace)
         return qn * n_real
 
-    def _scan_filtered_host(self, queries: np.ndarray,
-                            allow: Sequence[np.ndarray], k: int,
-                            out_v: np.ndarray, out_i: np.ndarray,
+    def _scan_filtered_host(self, queries: np.ndarray, allow: Sequence,
+                            k: int, out_v: np.ndarray, out_i: np.ndarray,
                             trace=None) -> int:
         """Exact scores of each query's allowed rows, compacted and
         pending, in numpy; ties to the lower row."""
         METRICS.counter("knn_filter_host_queries").inc(queries.shape[0])
         with maybe_span(trace, "knn.filter") as sp:
-            rows = [np.unique(r) for r in self._allowed_rows(allow,
-                                                             pending=True)]
+            rows, cached, built = self._allowed_rows(allow, pending=True)
             if sp is not None:
-                sp.set(rows_allowed=int(sum(len(r) for r in rows)))
+                sp.set(rows_allowed=int(sum(len(r) for r in rows)),
+                       bitmaps_cached=cached, bitmaps_built=built)
         with maybe_span(trace, "knn.host_scan"):
             corpus, ids, _ = self._full_corpus()
             for q, r in enumerate(rows):

@@ -19,13 +19,15 @@ whole-table scan (see ref.py); with ``probe_mask``, queries whose probed
 buckets hold fewer than k rows surface (val=-inf, id=-1) padding at the
 tail -- the same contract the shard merge already truncates.  ``row_mask``
 (filtered kNN) swaps the probe mask for a per-(query, row) allow bit,
-packed 32 queries to an int32 word (:func:`pack_row_mask`), and runs the
-row-masked kernel or its twin under a dispatch name of its own.
+packed 32 queries to an int32 word (``ref.pack_row_mask`` is the layout's
+reference; :func:`row_mask_from_bitmaps` builds it on the device from
+per-query row bitmaps), and runs the row-masked kernel or its twin under a
+dispatch name of its own.
 """
 from __future__ import annotations
 
 import functools
-from typing import Sequence, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -107,25 +109,24 @@ def _pq_topk_xla_rowmask(luts: jnp.ndarray, codes: jnp.ndarray,
     return _topk_valid(s, n_valid, k)
 
 
-def pack_row_mask(rows: Sequence[np.ndarray], n: int,
-                  block_n: int = 512) -> np.ndarray:
-    """Per-query allowed row positions (each < ``n``; a repeated position
-    sets its bit once) -> the row-masked scan's packed allow bits,
-    [W, padded_rows(n)] int32 with W = ceil(padded_queries(Q) / 32): bit j
-    of word [w, r] says query 32*w + j may return row r.  Padded to the
-    shapes :func:`pq_adc_topk` pads the batch and code table to, so it can
-    be uploaded as is."""
-    qn = len(rows)
-    words = -(-padded_queries(qn) // 32)
-    cols = padded_rows(n, block_n)
-    counts = np.fromiter((len(r) for r in rows), np.int64, qn)
-    q = np.repeat(np.arange(qn), counts)
-    pos = (np.concatenate([np.asarray(r, np.int64) for r in rows])
-           if counts.sum() else np.zeros(0, np.int64))
-    packed = np.zeros(words * cols, np.uint32)
-    np.bitwise_or.at(packed, (q // 32) * cols + pos,
-                     np.left_shift(np.uint32(1), (q % 32).astype(np.uint32)))
-    return packed.view(np.int32).reshape(words, cols)
+@jax.jit
+def row_mask_from_bitmaps(bitmaps: jnp.ndarray) -> jnp.ndarray:
+    """Per-query row bitmaps [Qp, W] uint32 (bit r % 32 of word r // 32:
+    the query may return row r) -> the row-masked scan's packed allow bits
+    [ceil(Qp / 32), 32 W] int32 (bit j of word [w, r]: query 32 w + j may
+    return row r), the layout ``ref.pack_row_mask`` builds from row
+    positions.  A bit transposition of each 32 x 32 block of (query, row)
+    bits, run on the device so the host uploads the bitmaps as they are."""
+    qp, w = bitmaps.shape
+    groups = -(-qp // 32)
+    b = jnp.pad(bitmaps.astype(jnp.uint32), ((0, 32 * groups - qp), (0, 0)))
+    bit = jnp.arange(32, dtype=jnp.uint32)
+    # allow[g, j, c, i]: query 32 g + j may return row 32 c + i
+    allow = (b.reshape(groups, 32, w, 1) >> bit) & 1
+    words = jnp.sum(allow << bit[None, :, None, None], axis=1,
+                    dtype=jnp.uint32)                        # [g, w, 32]
+    return jax.lax.bitcast_convert_type(words.reshape(groups, 32 * w),
+                                        jnp.int32)
 
 
 def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
@@ -140,8 +141,9 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     < ``n_valid``.  ``cscores`` / ``probe_mask`` require ``row_bucket``
     (see ref.py for the extended score decomposition); with ``probe_mask``,
     per-query positions past that query's probed row count come back as
-    (val=-inf, id=-1) padding.  ``row_mask`` (:func:`pack_row_mask`'s
-    packed allow bits, already padded, on the host or the device) takes
+    (val=-inf, id=-1) padding.  ``row_mask`` (the packed allow bits of
+    :func:`row_mask_from_bitmaps` or ``ref.pack_row_mask``, already
+    padded, on the host or the device) takes
     the row-masked scan, ``pq_scan_rowmask``, instead: every bucket is
     scored and each query sees only its allowed rows, with the same
     (-inf, -1) tail where it has fewer than k'; it excludes

@@ -22,16 +22,18 @@ term, masked to the probed buckets):
 
 ``row_mask [Q, N]`` (bool, filtered kNN) pins each row a query's filter
 excludes to -inf; the kernel takes the same bits packed 32 queries to a
-word (``ops.pack_row_mask``).
+word, the layout :func:`pack_row_mask` builds from row positions.
 
 Tie-breaking matches ``jax.lax.top_k`` (equal scores -> lower row index),
 so candidate ids are byte-comparable against the kernel and the XLA twin.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
+
+from repro.kernels.dispatch import padded_queries, padded_rows
 
 
 def pq_scores_ref(luts, codes, bias=None, row_bucket=None, cscores=None,
@@ -79,3 +81,25 @@ def pq_adc_topk_ref(luts, codes, k: int, n_valid: int = -1, bias=None,
         # (val=-inf, id=-1)
         idx = np.where(np.isfinite(vals), idx, -1)
     return vals.astype(np.float32), idx.astype(np.int32)
+
+
+def pack_row_mask(rows: Sequence[np.ndarray], n: int,
+                  block_n: int = 512) -> np.ndarray:
+    """Per-query allowed row positions (each < ``n``; a repeated position
+    sets its bit once) -> the row-masked scan's packed allow bits,
+    [W, padded_rows(n)] int32 with W = ceil(padded_queries(Q) / 32): bit j
+    of word [w, r] says query 32*w + j may return row r.  Padded to the
+    shapes ``ops.pq_adc_topk`` pads the batch and code table to.  The
+    layout's reference: the served path builds it on the device
+    (``ops.row_mask_from_bitmaps``)."""
+    qn = len(rows)
+    words = -(-padded_queries(qn) // 32)
+    cols = padded_rows(n, block_n)
+    counts = np.fromiter((len(r) for r in rows), np.int64, qn)
+    q = np.repeat(np.arange(qn), counts)
+    pos = (np.concatenate([np.asarray(r, np.int64) for r in rows])
+           if counts.sum() else np.zeros(0, np.int64))
+    packed = np.zeros(words * cols, np.uint32)
+    np.bitwise_or.at(packed, (q // 32) * cols + pos,
+                     np.left_shift(np.uint32(1), (q % 32).astype(np.uint32)))
+    return packed.view(np.int32).reshape(words, cols)

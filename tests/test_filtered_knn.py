@@ -6,9 +6,11 @@ import numpy as np
 import pytest
 
 from repro.configs.pandadb import PandaDBConfig, VectorIndexConfig
+from repro.core.scalar_index import InvertedIndex
 from repro.core.vector_index import IVFIndex
 from repro.kernels.dispatch import METRICS, dispatch_counts
-from repro.kernels.pq_scan import pack_row_mask, pq_adc_topk, pq_adc_topk_ref
+from repro.kernels.pq_scan import (pack_row_mask, pq_adc_topk,
+                                   pq_adc_topk_ref, row_mask_from_bitmaps)
 from repro.kernels.pq_scan.pq_scan import unpack_row_mask
 
 
@@ -377,3 +379,259 @@ def test_coordinator_knn_with_tag_filters(tagged, n_shards):
         db.knn("emb", q[:1], K, filters=[()], filter_key="tags")
     with pytest.raises(KeyError):
         db.knn("emb", q[:1], K, filters=[("t0",)], filter_key="nope")
+
+
+# -- row bitmaps: the device-built mask, the cache, the coordinator's ANDs -----
+
+
+def _bare_index(n, seed=0):
+    """A compacted index of ``n`` rows, sparse ids in shuffled row order;
+    only the id -> row layout matters to the filter."""
+    ids = np.random.default_rng(seed).permutation(n) * 3 + 7
+    return IVFIndex(VectorIndexConfig(dim=1), np.zeros((4, 1), np.float32),
+                    np.zeros(n, np.int32), np.zeros((n, 1), np.float32), ids)
+
+
+@pytest.mark.parametrize("qn", [1, 31, 32, 33, 256])
+def test_device_row_mask_matches_pack_row_mask(qn):
+    """Each query's row bitmap, transposed on the device, is bit for bit
+    ``pack_row_mask`` of the same row sets: empty filters, ids with no
+    row, the last real row, one-term and two-term ANDs of id arrays and of
+    postings long enough to be cached; no bit past the last real row."""
+    from repro.core.scalar_index import Posting
+    from repro.kernels.dispatch import padded_queries, padded_rows
+    n = 1100
+    ix = _bare_index(n)
+    rows = padded_rows(n, ix.cfg.block_n)
+    row_of = {int(i): r for r, i in enumerate(ix.ids)}
+    last = int(ix.ids[-1])
+    rng = np.random.default_rng(qn)
+    big = [Posting(np.sort(rng.choice(ix.ids, 600, replace=False)),
+                   (-1, 0), f"big{j}") for j in range(3)]
+    allow, want = [], []
+    for q in range(qn):
+        kind = q % 6
+        a = np.append(rng.choice(ix.ids, rng.integers(0, 300)), [last, 10**9])
+        b = rng.choice(ix.ids, 500)
+        if kind == 0:
+            f = np.zeros(0, np.int64)
+        elif kind == 1:
+            f = a
+        elif kind == 2:
+            f = (a, b)
+        elif kind == 3:
+            f = big[q % 3]
+        elif kind == 4:
+            f = (big[q % 3], a)
+        else:
+            f = (big[0], big[1], b)
+        terms = f if isinstance(f, tuple) else (f,)
+        sets = [{row_of[int(i)] for i in (t.ids if isinstance(t, Posting)
+                                           else t) if int(i) in row_of}
+                for t in terms]
+        allow.append(f)
+        want.append(np.array(sorted(set.intersection(*sets)), np.int64))
+    words, _, _ = ix._filter_words(allow, padded_queries(qn))
+    assert words.shape == (padded_queries(qn), rows // 32)
+    assert words.dtype == np.uint32
+    assert np.array_equal(np.asarray(row_mask_from_bitmaps(words)),
+                          pack_row_mask(want, n))
+    bits = np.unpackbits(words.view(np.uint8), axis=1, bitorder="little")
+    assert not bits[:, n:].any() and not bits[qn:].any()
+    assert int(np.bitwise_count(words).sum()) == sum(map(len, want))
+
+
+def _tag_index(ids, sizes, seed=0):
+    """An inverted index whose tag ``s<k>`` is on ``k`` ids drawn from
+    ``ids``."""
+    rng = np.random.default_rng(seed)
+    return InvertedIndex({f"s{k}": np.sort(rng.choice(ids, k, replace=False))
+                          for k in sizes})
+
+
+def _bitmap_counts():
+    return (METRICS.counter("knn_filter_bitmap:cached").value,
+            METRICS.counter("knn_filter_bitmap:built").value)
+
+
+def test_postings_at_the_threshold_cache_or_build_alike(corpus):
+    """A posting of padded_rows / 64 ids keeps its bitmap, one id fewer is
+    built every call; both answer as the same filter given as ids."""
+    from repro.kernels.dispatch import padded_rows
+    vecs, ids = corpus
+    ix = _index(corpus)
+    edge = padded_rows(len(ix.ids), ix.cfg.block_n) // 64
+    inv = _tag_index(ids, [edge, edge - 1], seed=21)
+    q = np.random.default_rng(22).integers(0, 64, (8, D)).astype(np.float32)
+    tags = [f"s{edge}", f"s{edge - 1}"] * 4
+    as_ids = [inv.lookup(t) for t in tags]
+    runs = []
+    for _ in range(2):
+        c0, b0 = _bitmap_counts()
+        runs.append(ix.search_many(q, K, allow=[inv.posting(t)
+                                                for t in tags]))
+        c1, b1 = _bitmap_counts()
+        runs[-1] += (c1 - c0, b1 - b0)
+    assert runs[0][2:] == (0, 2) and runs[1][2:] == (1, 1)
+    v, i = ix.search_many(q, K, allow=as_ids)
+    for rv, ri, _, _ in runs:
+        assert np.array_equal(ri, i) and np.array_equal(rv, v)
+    _check(vecs, ids, q, as_ids, v, i, exact=False)
+
+
+def test_index_insert_is_seen_by_a_cached_bitmap(corpus):
+    """A tag whose bitmap is cached, then given to one more node through
+    ``InvertedIndex.insert``: the next filtered answer holds that node."""
+    vecs, ids = corpus
+    ix = _index(corpus)
+    inv = _tag_index(ids[1:], [600], seed=23)
+    new = int(ids[0])
+    assert new not in inv.lookup("s600")
+    q = np.repeat(vecs[:1], 4, axis=0)
+    _, i = ix.search_many(q, K, allow=[inv.posting("s600")] * 4)
+    assert new not in i
+    c0, _ = _bitmap_counts()
+    inv.insert("s600 other", new)
+    _, i = ix.search_many(q, K, allow=[inv.posting("s600")] * 4)
+    assert _bitmap_counts()[0] == c0            # the old bitmap unused
+    assert (i[:, 0] == new).all()
+    _check(vecs, ids, q, [inv.lookup("s600")] * 4, *ix.search_many(
+        q, K, allow=[inv.posting("s600")] * 4), exact=False)
+
+
+def test_compaction_keeps_cached_filters_exact(corpus):
+    """Rows appended and compacted move every row a cached bitmap named:
+    answers with pending rows (the host path) and after the compaction
+    (the row-masked scan) stay inside the filter, with exact distances,
+    and see the new rows."""
+    vecs, ids = corpus
+    ix = _index(corpus)
+    inv = _tag_index(ids, [900, 30], seed=24)
+    q = np.random.default_rng(25).integers(0, 64, (6, D)).astype(np.float32)
+    filt = [(inv.posting("s900"),), (inv.posting("s30"),),
+            (inv.posting("s900"), inv.posting("s30"))] * 2
+    ix.search_many(q, K, allow=filt)                    # caches s900
+    rng = np.random.default_rng(26)
+    extra = q + rng.integers(0, 2, q.shape).astype(np.float32)
+    extra_ids = 3 * (N + np.arange(len(q))) + 7
+    ix.insert_many(extra, extra_ids)
+    for t in extra_ids:
+        inv.insert("s900 s30", int(t))
+    allv = np.concatenate([vecs, extra])
+    allid = np.concatenate([ids, extra_ids])
+
+    def answer():
+        f = [tuple(inv.posting(p.token) for p in terms) for terms in filt]
+        ref = [np.intersect1d(*[inv.lookup(p.token) for p in terms])
+               if len(terms) > 1 else inv.lookup(terms[0].token)
+               for terms in filt]
+        v, i = ix.search_many(q, K, allow=f, mode="fused")
+        _check(allv, allid, q, ref, v, i, exact=False)
+        assert (i[:, 0] == extra_ids).all()     # each query's own new row
+        return i
+
+    h0, gen = _host_queries(), ix.generation
+    answer()
+    assert _host_queries() == h0 + len(q)
+    ix.compact()
+    assert ix.generation == gen + 1 and ix.pending_count == 0
+    answer()
+    assert _host_queries() == h0 + len(q)
+
+
+def test_three_tag_and_and_a_tag_absent_from_a_shard():
+    """At P = 2: a three-tag AND, and a tag that only one shard's images
+    carry, alone and ANDed, match a float64 brute force exactly, and the
+    bitmap counters and the shard's ``knn.filter`` span count the terms."""
+    from repro.obs.metrics import prometheus_dump
+    from repro.obs.trace import Trace
+    rng = np.random.default_rng(31)
+    n = 1200
+    vecs = rng.integers(0, 64, (n, D)).astype(np.float32)
+    words = [f"t{j}" for j in range(10)]
+    tags = [list(rng.choice(words, size=rng.integers(1, 6), replace=False))
+            for _ in range(n)]
+    tags[5] = ["t0", "t1", "t2", "solo"]
+    db = _tag_db(2, vecs, tags)
+    idxs = [db.read_db(s).scalar_indexes["tags"] for s in db.active]
+    assert sorted(len(ix.lookup("solo")) for ix in idxs) == [0, 1]
+    filters = [("t0", "t1", "t2"), ("solo",), ("SOLO", "t0"),
+               ("t3", "t4", "t5"), ("t1", "solo", "t2"), ("solo", "t9")]
+    q = rng.integers(0, 64, (len(filters), D)).astype(np.float32)
+    c0, b0 = _bitmap_counts()
+    trace = Trace("knn")
+    v, i = db.knn("emb", q, K, mode="fused", filters=filters,
+                  filter_key="tags", trace=trace)
+    trace.finish()
+    allowed = 0
+    for qq, f, vv, ii in zip(q, filters, v, i):
+        a = np.array([b for b, t in enumerate(tags)
+                      if {w.lower() for w in f} <= set(t)], np.int64)
+        allowed += len(a)
+        d = ((vecs[a].astype(np.float64) - qq) ** 2).sum(1)
+        assert len(a) <= 80                    # every allowed row re-ranked
+        got = ii[ii >= 0]
+        assert np.isin(got, a).all() and len(got) == min(K, len(a))
+        np.testing.assert_allclose(-vv[:len(got)], np.sort(d)[:len(got)],
+                                   rtol=1e-6)
+    assert (i[1:3, 0] == 5).all() and (i[4, 0] == 5) and (i[5] == -1).all()
+    c1, b1 = _bitmap_counts()
+    spans = [s for s in trace.find("knn.filter") if "rows_allowed" in s.attrs]
+    assert len(spans) == 2
+    assert sum(s.attrs["rows_allowed"] for s in spans) == allowed
+    assert sum(s.attrs["bitmaps_cached"] for s in spans) == c1 - c0
+    assert sum(s.attrs["bitmaps_built"] for s in spans) == b1 - b0 > 0
+    dump = prometheus_dump()
+    assert "pandadb_knn_filter_bitmap_cached_total" in dump
+    assert "pandadb_knn_filter_bitmap_built_total" in dump
+
+
+def test_bitmap_cache_under_contending_threads():
+    """Threads that share one index piece and alternate between two
+    inverted indexes keep replacing its bitmap cache while they fill it;
+    every batch still reads the bitmaps built fresh for it."""
+    import sys
+    import threading
+    ix = _bare_index(3000, seed=41)
+    invs = [_tag_index(ix.ids, [60, 300, 900], seed=s) for s in (42, 43)]
+    tags = [("s60",), ("s300", "s900"), ("s900",), ("s60", "s300")]
+    want = [ix._filter_words([tuple(map(inv.lookup, f)) for f in tags])[0]
+            for inv in invs]
+    bad, done = [], []
+
+    def work(j):
+        for r in range(40):
+            inv = invs[(j + r) % 2]
+            got = ix._filter_words([tuple(map(inv.posting, f))
+                                    for f in tags])[0]
+            if not np.array_equal(got, want[(j + r) % 2]):
+                bad.append((j, r))
+        done.append(j)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(j,))
+                   for j in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert sorted(done) == list(range(16)) and not bad
+
+
+def test_a_tuple_filter_holds_terms_only():
+    """A tuple filter ANDs postings or id arrays; a tuple of ids is
+    refused rather than read as an AND of one-id terms, and an empty
+    tuple names no term."""
+    ix = _bare_index(100)
+    ids = ix.ids[:3]
+    words, _, _ = ix._filter_words([(ids, ids[:2]), ids])
+    assert np.bitwise_count(words).sum(axis=1).tolist() == [2, 3]
+    with pytest.raises(TypeError):
+        ix._filter_words([tuple(int(i) for i in ids)])
+    with pytest.raises(ValueError):
+        ix._filter_words([()])

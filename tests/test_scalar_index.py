@@ -29,7 +29,9 @@ def test_inverted_index_lookup():
     assert sorted(idx.lookup("cat").tolist()) == [1, 3]
     assert idx.lookup("Tobacco").tolist() == [4]   # case-folded
     assert idx.lookup("missing").size == 0
-    assert idx.lookup_all(["tobacco", "leaf"]).tolist() == [4]
+    assert idx.posting("LEAF").ids.tolist() == [4]
+    p = idx.posting("leaf")
+    assert (p.owner, p.token) == ((idx.uid, 0), "leaf")
 
 
 def test_inverted_index_dynamic_insert():

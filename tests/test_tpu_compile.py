@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels.ivf_scan.ivf_scan import ivf_scan_topk_pallas
+from repro.kernels.pq_scan.ops import row_mask_from_bitmaps
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
                                            pq_adc_topk_pallas,
                                            pq_adc_topk_rowmask_pallas)
@@ -126,3 +127,14 @@ def test_pq_scan_rowmask_compiles_at_served_shape(one_chip, qn):
             _spec((), i32, sh))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("qn", [32, 256])
+def test_row_mask_transpose_compiles_at_served_shape(one_chip, qn):
+    """The filtered cell's row bitmaps [Q, N / 32] uint32, transposed on
+    the device into the row-masked scan's packed [Q / 32, N] int32."""
+    lowered = row_mask_from_bitmaps.lower(
+        _spec((qn, N // 32), jnp.uint32, one_chip))
+    out = lowered.out_info
+    assert out.shape == (qn // 32, N) and out.dtype == jnp.int32
+    lowered.compile()
