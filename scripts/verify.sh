@@ -1,9 +1,10 @@
 #!/usr/bin/env bash
-# Tier-1 verify: the full test suite plus a quick serving-benchmark smoke.
+# Tier-1 verify: the full test suite.  Performance is measured on the chip
+# by the benchmark in bench/ (bench/run.py), not here.
 #
-#   scripts/verify.sh            # full tests + bench smoke
-#   scripts/verify.sh --fast     # full tests only
-#   scripts/verify.sh --quick    # tier-1 minus `slow` markers, no bench
+#   scripts/verify.sh            # full tests
+#   scripts/verify.sh --fast     # the same
+#   scripts/verify.sh --quick    # tier-1 minus `slow` markers
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -31,12 +32,6 @@ if [[ "$MODE" == "--quick" ]]; then
       --ignore=tests/test_pq_index.py --ignore=tests/test_kernels.py
 else
   python -m pytest -x -q
-fi
-
-if [[ -z "$MODE" ]]; then
-  echo
-  echo "== bench smoke: prepared-statement serving throughput =="
-  PYTHONPATH="src:.:${PYTHONPATH}" python benchmarks/bench_throughput.py --smoke
 fi
 
 echo

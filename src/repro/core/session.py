@@ -291,7 +291,8 @@ class Cursor:
                  keys: Tuple[str, ...] = (),
                  rwlock: Optional[RWLock] = None,
                  trace: Optional[Trace] = None,
-                 profile: Optional[QueryProfile] = None) -> None:
+                 profile: Optional[QueryProfile] = None,
+                 own_trace: bool = True) -> None:
         self.context = ctx
         self.batch_rows = batch_rows
         self._keys = keys
@@ -304,10 +305,17 @@ class Cursor:
         self.batches_fetched = 0
         self._deadline = None   # ClusterCursor sets this (it has no ctx)
         self.trace = trace          # per-query span tree (None = not traced)
+        # a trace the caller passed in (the serving engine's request trace)
+        # is the caller's to finish, after its own spans close
+        self._own_trace = own_trace
         self._profile = profile     # QueryProfile when PROFILE/profile=True
         self._profile_plan = plan
-        if self._exhausted and trace is not None:
-            trace.finish()
+        if self._exhausted:
+            self._finish_trace()
+
+    def _finish_trace(self) -> None:
+        if self.trace is not None and self._own_trace:
+            self.trace.finish()
 
     def keys(self) -> Tuple[str, ...]:
         return self._keys
@@ -346,7 +354,7 @@ class Cursor:
             with self.trace.span("cursor.pull", parent=self.trace.root):
                 return self._next_batch_inner()
         except BaseException:
-            self.trace.finish()
+            self._finish_trace()
             raise
 
     def _next_batch_inner(self) -> Optional[List[Dict]]:
@@ -363,8 +371,7 @@ class Cursor:
             batch = self._next_batch()
             if batch is None:
                 self._exhausted = True
-                if self.trace is not None:
-                    self.trace.finish()
+                self._finish_trace()
                 return False
             self.batches_fetched += 1
             self._buf.extend(batch)
@@ -389,8 +396,7 @@ class Cursor:
             batch = self._next_batch()
             if batch is None:
                 self._exhausted = True
-                if self.trace is not None:
-                    self.trace.finish()
+                self._finish_trace()
                 return
             self.batches_fetched += 1
             yield batch
@@ -416,8 +422,7 @@ class Cursor:
             self._gen.close()
         self._buf.clear()
         self._exhausted = True
-        if self.trace is not None:
-            self.trace.finish()
+        self._finish_trace()
 
     # -- PROFILE -----------------------------------------------------------------
 
@@ -432,8 +437,8 @@ class Cursor:
         the report covers whatever has executed so far."""
         if self._profile is None:
             return None
-        if self.trace is not None and self._exhausted:
-            self.trace.finish()
+        if self._exhausted:
+            self._finish_trace()
         return self._profile.report(self._profile_plan, trace=self.trace,
                                     deadline=self.deadline,
                                     include_trace=include_trace)
@@ -617,20 +622,23 @@ class Session:
         ``PROFILE`` query prefix) traces + profiles this statement
         regardless of the tracer switch; read ``cursor.profile_report()``
         after consuming.  ``trace`` lets a caller that already opened a
-        span tree (the serving engine) pass it down."""
+        span tree (the serving engine) pass it down; that caller finishes
+        it, the cursor does not."""
         if self._closed:
             raise RuntimeError("session is closed")
         params = {**(parameters or {}), **params}
         skeleton = skeleton_of(text)
         profile = profile or skeleton[:8].upper() == "PROFILE "
-        if trace is None:
+        own_trace = trace is None
+        if own_trace:
             trace = self.db.tracer.begin("query", force=profile,
                                          skeleton=skeleton)
         if self.cache is None or skeleton[:6].upper() == "CREATE":
             return self._run_parsed(skeleton, parse_query(text), params,
                                     optimized=optimized, text=text,
                                     deadline_ms=deadline_ms,
-                                    profile=profile, trace=trace)
+                                    profile=profile, trace=trace,
+                                    own_trace=own_trace)
         # fast path: resolve through the plan cache without parsing
         self.db.stats.refresh_from_graph(self.db.graph)
         self.db.stats.refresh_extractor_stats(self.db.registry)
@@ -645,13 +653,15 @@ class Session:
                     key, lambda: self._parse_and_plan(text, optimized))
                 sp.set(cache="miss" if self.cache.misses > misses0 else "hit")
         return self._execute(q, plan, params, text, deadline_ms=deadline_ms,
-                             profile=profile, trace=trace)
+                             profile=profile, trace=trace,
+                             own_trace=own_trace)
 
     def _run_parsed(self, skeleton: str, q: Query, params: Dict[str, Any],
                     optimized: bool, text: str,
                     deadline_ms: Optional[float] = None,
                     profile: bool = False,
-                    trace: Optional[Trace] = None) -> Cursor:
+                    trace: Optional[Trace] = None,
+                    own_trace: bool = True) -> Cursor:
         """Execute an already-parsed query (run() and PreparedStatement
         both land here)."""
         if self._closed:
@@ -663,13 +673,15 @@ class Session:
         if isinstance(q, CreateQuery):
             return self._execute(q, None, params, text,
                                  deadline_ms=deadline_ms,
-                                 profile=profile, trace=trace)
+                                 profile=profile, trace=trace,
+                                 own_trace=own_trace)
         self.db.stats.refresh_from_graph(self.db.graph)
         self.db.stats.refresh_extractor_stats(self.db.registry)
         if self.cache is None:
             return self._execute(q, plan_query(self.db, q, optimized),
                                  params, text, deadline_ms=deadline_ms,
-                                 profile=profile, trace=trace)
+                                 profile=profile, trace=trace,
+                                 own_trace=own_trace)
         key = (skeleton, optimized, self.db.stats.epoch)
         if trace is None:
             _, plan = self.cache.get_or_build(
@@ -681,7 +693,8 @@ class Session:
                     key, lambda: (q, plan_query(self.db, q, optimized)))
                 sp.set(cache="miss" if self.cache.misses > misses0 else "hit")
         return self._execute(q, plan, params, text, deadline_ms=deadline_ms,
-                             profile=profile, trace=trace)
+                             profile=profile, trace=trace,
+                             own_trace=own_trace)
 
     def _parse_and_plan(self, text: str,
                         optimized: bool) -> Tuple[Query, Optional[lp.PlanOp]]:
@@ -694,7 +707,8 @@ class Session:
                  params: Dict[str, Any], text: str,
                  deadline_ms: Optional[float] = None,
                  profile: bool = False,
-                 trace: Optional[Trace] = None) -> Cursor:
+                 trace: Optional[Trace] = None,
+                 own_trace: bool = True) -> Cursor:
         missing = query_params(q) - set(params)
         if missing:
             raise KeyError(f"unbound parameters: "
@@ -713,24 +727,26 @@ class Session:
                                trace=trace, profile=qprof)
         if isinstance(q, CreateQuery):
             self._execute_write(q, text, params)
-            return Cursor(ctx, None, trace=trace, profile=qprof)
+            return Cursor(ctx, None, trace=trace, profile=qprof,
+                          own_trace=own_trace)
         assert plan is not None
         if self._tx is not None:
             # inside a transaction the scope already holds the lock; rows
             # must not stream past its release, so materialize here
             cur = Cursor(ctx, plan, self.batch_rows,
                          keys=_projection_keys(q),
-                         trace=trace, profile=qprof)
+                         trace=trace, profile=qprof, own_trace=own_trace)
             rows = cur.fetchall()
             out = Cursor(ctx, None, keys=cur.keys(),
-                         trace=trace, profile=qprof)
+                         trace=trace, profile=qprof, own_trace=own_trace)
             out._profile_plan = plan
             out._buf.extend(rows)
             return out
         # otherwise each chunk pull takes the shared lock side so writers
         # never race a mid-chunk scan
         return Cursor(ctx, plan, self.batch_rows, keys=_projection_keys(q),
-                      rwlock=self.db.rwlock, trace=trace, profile=qprof)
+                      rwlock=self.db.rwlock, trace=trace, profile=qprof,
+                      own_trace=own_trace)
 
     def _execute_write(self, q: CreateQuery, text: str,
                        params: Dict[str, Any]) -> None:

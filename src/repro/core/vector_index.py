@@ -26,9 +26,9 @@ float scan per query batch from observed throughputs
 (``StatisticsService.choose_knn_scan``).
 
 Distributed layout (paper §VII-A: property data sharded): centroids are
-replicated, bucket contents are sharded over the ``data`` axis; a query does
-a local scan per shard + per-shard top-k + a tiny all-gather merge --
-``distributed_knn`` below is that collective schedule, runnable on any mesh.
+replicated, bucket contents are sharded (:meth:`IVFIndex.shard`); a query
+does a local scan per shard + per-shard top-k + a k-way merge --
+:func:`scatter_gather_knn` below is that schedule.
 """
 from __future__ import annotations
 
@@ -97,33 +97,13 @@ def scan_topk(q: jnp.ndarray, corpus: jnp.ndarray, ids: jnp.ndarray,
     return vals, ids[idx]
 
 
-@partial(jax.jit, static_argnames=("k", "metric"))
-def masked_scan_topk(q: jnp.ndarray, corpus: jnp.ndarray,
-                     row_bucket: jnp.ndarray, probe_mask: jnp.ndarray,
-                     k: int, metric: str
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Dense probe scan: ONE fused [Q, N] score matmul with each query's
-    non-probed buckets masked to -inf before the top-k.
-
-    ``row_bucket[N]`` is each corpus row's bucket id (padding rows use an
-    out-of-range id), ``probe_mask[Q, m+1]`` is True at the buckets a query
-    probes (column m, the padding bucket, is always False).  Scans the whole
-    table, so it only wins when the batch's probe signatures are scattered
-    enough that per-signature gathers would touch >= the table anyway --
-    ``IVFIndex.search_many`` makes that call."""
-    s = pairwise_scores(q, corpus, metric)              # [Q, N]
-    s = jnp.where(probe_mask[:, row_bucket], s, -jnp.inf)
-    vals, idx = jax.lax.top_k(s, k)
-    return vals, idx
-
-
 def merge_topk(vals_parts: jnp.ndarray, ids_parts: jnp.ndarray, k: int
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Merge per-shard top-k: [P, Q, k] -> [Q, k] (associative).
 
     Padding entries (val=-inf, id=-1) sink to the tail of the merge; callers
     that may hold fewer than ``k`` real candidates in total should truncate
-    or mask afterwards (see :func:`distributed_knn`)."""
+    or mask afterwards (:func:`scatter_gather_knn` does)."""
     p, qn, kk = vals_parts.shape
     flat_v = jnp.transpose(vals_parts, (1, 0, 2)).reshape(qn, p * kk)
     flat_i = jnp.transpose(ids_parts, (1, 0, 2)).reshape(qn, p * kk)
@@ -164,8 +144,8 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     ``merge_topk_dev`` reduce (the Pallas merge kernel on TPU, its jitted
     XLA twin elsewhere) -> truncation of shard padding to min(k, total
     rows).  Every scatter-gather kNN in the tree -- ``ShardedPandaDB.knn``,
-    :func:`distributed_knn`, the serving path -- routes through here, so
-    the merge semantics cannot drift.
+    the serving path -- routes through here, so the merge semantics cannot
+    drift.
 
     Output invariant (the ``merge_topk`` padding contract, enforced here
     rather than trusted): a position holds id=-1 exactly where its value is
@@ -304,52 +284,6 @@ def scatter_gather_knn(shards: Sequence["IVFIndex"], queries: np.ndarray,
     # in total), the id is -1 -- whatever payload the shard windows carried
     out_i[:, :kk] = np.where(np.isfinite(v), i, -1)
     return out_v[:, :k], out_i[:, :k]
-
-
-def flat_shard_view(corpus: np.ndarray, ids: np.ndarray, metric: str = "l2",
-                    pq: Optional["PQCodebook"] = None,
-                    codes: Optional[np.ndarray] = None) -> "IVFIndex":
-    """Wrap raw (corpus, ids) arrays as a single-bucket :class:`IVFIndex`
-    so loose shards ride the same scan + merge machinery as built indexes
-    (cosine rows are normalized exactly as :meth:`IVFIndex.build` would)."""
-    corpus = np.asarray(corpus, np.float32)
-    if metric == "cosine" and corpus.size:
-        corpus = corpus / np.maximum(
-            np.linalg.norm(corpus, axis=-1, keepdims=True), 1e-9)
-    n, dim = corpus.shape
-    cfg = VectorIndexConfig(dim=dim, metric=metric, min_buckets=1,
-                            vectors_per_bucket=max(1, n), nprobe=1)
-    return IVFIndex(cfg, np.zeros((1, dim), np.float32),
-                    np.zeros(n, np.int64), corpus,
-                    np.asarray(ids), pq=pq, codes=codes)
-
-
-def distributed_knn(q: jnp.ndarray, corpus_shards: List[jnp.ndarray],
-                    id_shards: List[jnp.ndarray], k: int, metric: str = "l2",
-                    mode: str = "float", pq: Optional["PQCodebook"] = None,
-                    code_shards: Optional[List[np.ndarray]] = None
-                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Reference collective schedule: local scan -> local top-k -> merge.
-    (On a real mesh the shard loop is the data axis and the merge is one
-    all_gather of [k] pairs per shard; see distributed/collectives.py.)
-
-    A thin wrapper over :func:`scatter_gather_knn` -- the cluster merge
-    path -- so this host-loop reference and ``ShardedPandaDB`` can never
-    drift.  ``mode="adc"`` with ``pq`` + ``code_shards`` runs the PQ
-    two-stage scan per shard (ADC top-k' + exact re-rank, returned scores
-    exact).  The output is truncated to min(k, total rows), so the -1/-inf
-    padding a small shard contributes can never leak into caller-visible
-    results."""
-    views = []
-    for s, (shard, ids) in enumerate(zip(corpus_shards, id_shards)):
-        codes = code_shards[s] if code_shards is not None else None
-        views.append(flat_shard_view(np.asarray(shard), np.asarray(ids),
-                                     metric, pq=pq, codes=codes))
-    v, i = scatter_gather_knn(views, np.asarray(q, np.float32), k,
-                              nprobe=1, mode=mode)
-    total = sum(int(np.asarray(s).shape[0]) for s in corpus_shards)
-    kk = min(k, total)
-    return jnp.asarray(v[:, :kk]), jnp.asarray(i[:, :kk])
 
 
 # ---------------------------------------------------------------------------
@@ -738,7 +672,7 @@ class IVFIndex:
         appends (the float-scan view; ADC scans gather through
         :meth:`_gather_codes` instead and never copy vectors)."""
         if len(buckets) == self.centroids.shape[0]:
-            corpus, ids, _ = self._full_corpus()   # exact mode: no copy
+            corpus, ids = self._full_corpus()   # exact mode: no copy
             return corpus, ids
         segs = [self.bucket_slice(int(b)) for b in buckets]
         rows = (np.concatenate([np.arange(lo, hi) for lo, hi in segs])
@@ -774,33 +708,21 @@ class IVFIndex:
         """Batched two-phase kNN over the whole query set.
 
         Phase 1: one centroid scoring + top-``nprobe`` for all queries.
-        Phase 2 picks a batched scan layout:
+        Phase 2 takes one of these paths:
 
-        * **signature groups** -- queries sharing a probe signature (the
-          same bucket set) scan together: their buckets are gathered once
-          into a corpus padded to a ``block_n`` multiple (stable shapes;
-          the kernel precondition) and dispatched through ``ivf_scan_topk``
-          (Pallas kernel on TPU, fused XLA scan elsewhere).  Wins when
-          queries cluster (few signatures) and always serves exact mode
-          (nprobe=m is one signature).
-        * **masked dense scan** (float mode) -- when the signatures are so
-          scattered that per-signature gathers would touch at least the
-          whole table (#signatures x nprobe >= m), ONE fused scan of the
-          full corpus with each query's non-probed buckets masked to -inf
-          (:func:`masked_scan_topk`).  Same candidate sets, one device
-          call.
-        * **ADC + exact re-rank** (PQ mode) -- per-query score LUTs, an
-          asymmetric-distance top-k' scan of the probed buckets' uint8
-          codes through ``pq_adc_topk`` (k' = ``rerank_mult * k``), then an
-          exact re-rank of the k' candidates against the original float
-          vectors.  Returned scores are exact, so downstream similarity
-          thresholds are unaffected by quantization.
-
-        A single-query batch takes a host-side fast path that skips the
-        probe-signature grouping, block padding and device dispatch
-        entirely (the per-call overhead dominates one small scan).
-
-        * **fused probe->ADC->top-k** (PQ mode) -- ONE masked whole-table
+        * **signature groups** (float mode) -- queries sharing a probe
+          signature (the same bucket set) scan together: their buckets are
+          gathered once into a corpus padded to a ``block_n`` multiple
+          (stable shapes; the kernel precondition) and dispatched through
+          ``ivf_scan_topk`` (Pallas kernel on TPU, fused XLA scan
+          elsewhere).  Exact mode (nprobe=m) is one signature, one scan.
+        * **staged ADC + exact re-rank** (PQ mode) -- per-query score LUTs,
+          an asymmetric-distance top-k' scan of each signature's gathered
+          uint8 codes through ``pq_adc_topk`` (k' = ``rerank_mult * k``),
+          then an exact re-rank of the k' candidates against the original
+          float vectors.  Returned scores are exact, so downstream
+          similarity thresholds are unaffected by quantization.
+        * **fused probe->ADC->top-k'** (PQ mode) -- ONE masked whole-table
           ADC dispatch for the entire batch: every code row is scanned and
           rows of non-probed buckets are pinned to -inf *in-kernel*
           (``probe_mask``), so there are no per-signature gathers and no
@@ -808,6 +730,13 @@ class IVFIndex:
           candidate positions must be table rows); pending appends fall
           back to the staged ADC path.  Candidates, scores and tie order
           are identical to the staged path.
+        * **row-masked ADC** (filtered, ``allow``) -- one whole-table ADC
+          dispatch with each query's filter as a per-row mask; filtered
+          batches that cannot take it are scored exactly on the host
+          (:meth:`_search_filtered`).
+        * **single query** -- a host-side numpy path that skips the
+          signature grouping, block padding and device dispatch entirely
+          (the per-call overhead dominates one small scan).
 
         ``mode`` is ``"auto"`` (consult ``stats.choose_knn_scan`` when
         given, else ADC whenever PQ codebooks exist), ``"adc"``,
@@ -892,9 +821,6 @@ class IVFIndex:
                                                     k, out_v, out_i, rerank,
                                                     cterm, rerank_mult,
                                                     trace)
-            elif sigs.shape[0] > 1 and sigs.shape[0] * nprobe >= m:
-                rows_scanned = self._scan_dense(queries, probe, k,
-                                                out_v, out_i, trace)
             else:
                 rows_scanned = self._scan_groups(queries, sigs, inverse, k,
                                                  out_v, out_i, trace)
@@ -1472,7 +1398,7 @@ class IVFIndex:
                 sp.set(rows_allowed=int(sum(len(r) for r in rows)),
                        bitmaps_cached=cached, bitmaps_built=built)
         with maybe_span(trace, "knn.host_scan"):
-            corpus, ids, _ = self._full_corpus()
+            corpus, ids = self._full_corpus()
             for q, r in enumerate(rows):
                 if not len(r):
                     continue
@@ -1483,53 +1409,17 @@ class IVFIndex:
                 out_i[q, :len(top)] = ids[r[top]]
         return int(sum(len(r) for r in rows))
 
-    def _scan_dense(self, queries: np.ndarray, probe: np.ndarray, k: int,
-                    out_v: np.ndarray, out_i: np.ndarray,
-                    trace=None) -> int:
-        """One masked scan of the full table for scattered probe batches."""
-        m = self.centroids.shape[0]
-        qn = queries.shape[0]
-        with maybe_span(trace, "knn.gather"):
-            corpus, ids, row_bucket = self._full_corpus()
-        n_real = corpus.shape[0]
-        pad = (-n_real) % self.cfg.block_n
-        if pad:
-            corpus = np.concatenate(
-                [corpus, np.zeros((pad, corpus.shape[1]), np.float32)])
-            # padding rows live in bucket m, which no query ever probes
-            row_bucket = np.concatenate(
-                [row_bucket, np.full(pad, m, row_bucket.dtype)])
-        probe_mask = np.zeros((qn, m + 1), bool)
-        probe_mask[np.arange(qn)[:, None], probe] = True
-        k_eff = min(k, n_real)
-        with maybe_span(trace, "knn.scan") as sp:
-            count_h2d("masked_scan", sp, queries, corpus, row_bucket,
-                      probe_mask, kernel="masked_scan", impl="xla")
-            vals, idx = masked_scan_topk(
-                jnp.asarray(queries), jnp.asarray(corpus),
-                jnp.asarray(row_bucket), jnp.asarray(probe_mask), k_eff,
-                self.cfg.metric)
-            vals = np.asarray(vals)
-        gids = ids[np.clip(np.asarray(idx), 0, n_real - 1)]
-        out_v[:, :k_eff] = vals
-        out_i[:, :k_eff] = np.where(np.isfinite(vals), gids, -1)
-        return qn * n_real
-
-    def _full_corpus(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """(vectors, ids, bucket ids) over compacted + pending rows."""
+    def _full_corpus(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(vectors, ids) over compacted + pending rows."""
         if not self.pending_count:
-            return self.vectors, self.ids, self.bucket_of
+            return self.vectors, self.ids
         pend_v: List[np.ndarray] = []
         pend_i: List[int] = []
-        pend_b: List[int] = []
         for b in sorted(self._pend_vecs):
             pend_v += self._pend_vecs[b]
             pend_i += self._pend_ids[b]
-            pend_b += [b] * len(self._pend_vecs[b])
         return (np.concatenate([self.vectors, np.stack(pend_v)]),
-                np.concatenate([self.ids, np.asarray(pend_i, self.ids.dtype)]),
-                np.concatenate([self.bucket_of,
-                                np.asarray(pend_b, self.bucket_of.dtype)]))
+                np.concatenate([self.ids, np.asarray(pend_i, self.ids.dtype)]))
 
     def search_exact(self, queries: np.ndarray, k: int
                      ) -> Tuple[np.ndarray, np.ndarray]:

@@ -3,13 +3,13 @@
 Every kNN wrapper (``ivf_scan``, ``pq_scan``, ``topk_merge``) asks
 :func:`use_pallas` once per call.  The Pallas kernel runs on a TPU, or where
 a caller forces it (the interpret-mode tests), for k up to its kernel's cap
-in ``KERNEL_MAX_K``; above it the jitted XLA twin runs instead.  The
-extended PQ scan ``pq_scan_ext`` takes the kernel up to k = 128, the widest
-at which it measured faster than its twin (one gather pass over a [Q, N]
-float32 score matrix per subspace) at every batch size of the SIFT cell on
-a TPU v5e (PERF.md), and so does its row-masked sibling
-``pq_scan_rowmask`` (the same network); the other kernels keep 64, a bound
-set before any chip measurement.  Each decision is counted in ``METRICS`` (the ``pandadb``
+in ``KERNEL_MAX_K``; above it the jitted XLA twin runs instead.  The PQ
+scan ``pq_scan_ext`` takes the kernel up to k = 128, the widest at which it
+measured faster than its twin (one gather pass over a [Q, N] float32 score
+matrix per subspace) at every batch size of the SIFT cell on a TPU v5e
+(PERF.md), and so does its row-masked sibling ``pq_scan_rowmask`` (the
+same network); the other kernels keep 64, a bound set before any chip
+measurement.  Each decision is counted in ``METRICS`` (the ``pandadb``
 namespace) as ``kernel_dispatch:<kernel>:pallas`` or ``:xla``;
 a Pallas call that runs in interpret mode also counts ``:interpret``, so a
 run on the chip can show which code executed and that none of it was
@@ -30,8 +30,8 @@ from jax import monitoring
 
 from repro.obs.metrics import MetricsRegistry
 
-KERNEL_MAX_K = {"ivf_scan": 64, "pq_scan": 64, "pq_scan_ext": 128,
-                "pq_scan_rowmask": 128, "topk_merge": 64}
+KERNEL_MAX_K = {"ivf_scan": 64, "pq_scan_ext": 128, "pq_scan_rowmask": 128,
+                "topk_merge": 64}
 
 METRICS = MetricsRegistry("pandadb")
 

@@ -1,11 +1,15 @@
-"""Dispatching wrapper: Pallas ADC kernel on TPU, jitted XLA twin elsewhere.
+"""Dispatching wrapper: Pallas ADC kernels on TPU, jitted XLA twins elsewhere.
 
-The kernel path is exact for any k (per-tile top-k >= global contribution of
-that tile), so parity with ref.py is bitwise on candidate ids (the LUT sums
-are float32 adds in a different order).  Above the kernel's cap
-(``dispatch.KERNEL_MAX_K``: k' 128 for the extended scan, the widest at
-which it measured faster than the twin on the chip; 64 for the base scan)
-the XLA path runs.
+Two programs, each a Pallas kernel with an XLA twin: the extended scan
+``pq_scan_ext`` (LUT sum + per-row bias + per-bucket centroid term, rows of
+non-probed buckets masked; a call without residual terms scores with
+zero ones, the plain ADC scan) and the row-masked scan ``pq_scan_rowmask``
+(filtered kNN).  The kernel path is exact for any k (per-tile top-k >=
+global contribution of that tile), so parity with ref.py is bitwise on
+candidate ids (the LUT sums are float32 adds in a different order).  Above
+the kernels' cap (``dispatch.KERNEL_MAX_K``: k' 128, the widest at which
+the extended kernel measured faster than its twin on the chip) the XLA
+path runs.
 
 Code tables are rarely block_n multiples, so the wrapper pads the codes up
 to one and passes ``n_valid`` through: padded rows are masked to ``NEG``
@@ -36,35 +40,16 @@ import numpy as np
 from repro.kernels.dispatch import (count_h2d, impl_name, on_tpu, pad_rows,
                                     padded_queries, padded_rows, use_pallas)
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
-                                           pq_adc_topk_pallas,
                                            pq_adc_topk_rowmask_pallas,
                                            unpack_row_mask)
 
 _NEG_THRESH = -1.5e38   # kernel NEG mask values live below this
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _pq_topk_xla(luts: jnp.ndarray, codes: jnp.ndarray, n_valid: jnp.ndarray,
-                 k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Jitted XLA twin of the kernel: fused LUT gathers + padding mask +
-    top-k.  Scores accumulate in [Q, N] layout (one column gather per
-    subspace) so the top-k runs over contiguous rows -- the [N, Q]
-    transpose layout costs ~8x here.  ``n_valid`` is traced, so every
-    block-padded code-table shape compiles once and serves any padding
-    amount."""
-    qn, m, _ksub = luts.shape
-    codes = codes.astype(jnp.int32)
-    s = jnp.zeros((qn, codes.shape[0]), jnp.float32)
-    for j in range(m):                      # static unroll: M is small
-        s = s + luts[:, j, :][:, codes[:, j]]
-    cols = jnp.arange(codes.shape[0])[None, :]
-    s = jnp.where(cols >= n_valid, -jnp.inf, s)
-    vals, idx = jax.lax.top_k(s, k)
-    return vals, idx.astype(jnp.int32)
-
-
 def _ext_scores_xla(luts, codes, bias, rb, cscores) -> jnp.ndarray:
-    """[Q, N] LUT gathers + bias + per-row bucket term."""
+    """[Q, N] LUT gathers + bias + per-row bucket term.  Scores accumulate
+    in [Q, N] layout (one column gather per subspace) so the top-k runs
+    over contiguous rows; the [N, Q] layout costs ~8x here."""
     qn, m, _ksub = luts.shape
     codes = codes.astype(jnp.int32)
     s = jnp.zeros((qn, codes.shape[0]), jnp.float32)
@@ -139,17 +124,17 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
     Rows at positions >= ``n_valid`` (default: all of ``codes``) are treated
     as padding and excluded from the result; returned indices are always
     < ``n_valid``.  ``cscores`` / ``probe_mask`` require ``row_bucket``
-    (see ref.py for the extended score decomposition); with ``probe_mask``,
+    (see ref.py for the extended score decomposition); a term left out
+    scores as zero, every row in one probed bucket.  With ``probe_mask``,
     per-query positions past that query's probed row count come back as
     (val=-inf, id=-1) padding.  ``row_mask`` (the packed allow bits of
     :func:`row_mask_from_bitmaps` or ``ref.pack_row_mask``, already
     padded, on the host or the device) takes
-    the row-masked scan, ``pq_scan_rowmask``, instead: every bucket is
-    scored and each query sees only its allowed rows, with the same
-    (-inf, -1) tail where it has fewer than k'; it excludes
+    the row-masked scan, ``pq_scan_rowmask``, instead of ``pq_scan_ext``:
+    every bucket is scored and each query sees only its allowed rows, with
+    the same (-inf, -1) tail where it has fewer than k'; it excludes
     ``probe_mask``.  The padded operands' bytes count as
-    ``h2d_bytes:pq_scan`` (``pq_scan_ext`` or ``pq_scan_rowmask`` with any
-    extended argument; the row mask is the caller's to count);
+    ``h2d_bytes:<kernel>`` (the row mask is the caller's to count);
     ``span`` (a live ``knn.scan`` span, or None) gets them with ``kernel``
     and ``impl``."""
     n = codes.shape[0]
@@ -161,32 +146,18 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
         return (np.zeros((qn, 0), np.float32),
                 np.zeros((qn, 0), np.int32))
     rowmask = row_mask is not None
-    ext = rowmask or any(a is not None
-                         for a in (bias, row_bucket, cscores, probe_mask))
     if (cscores is not None or probe_mask is not None) and row_bucket is None:
         raise ValueError("cscores/probe_mask require row_bucket")
     if rowmask and probe_mask is not None:
         raise ValueError("row_mask and probe_mask exclude each other")
-    kernel = ("pq_scan_rowmask" if rowmask
-              else "pq_scan_ext" if ext else "pq_scan")
+    kernel = "pq_scan_rowmask" if rowmask else "pq_scan_ext"
     use_kernel = use_pallas(kernel, k, force_pallas)
     rows, qrows = padded_rows(n, block_n), padded_queries(qn)
     luts = pad_rows(luts, qrows, np.float32)
     codes = pad_rows(codes, rows)
-    if not ext:
-        count_h2d(kernel, span, luts, codes, kernel=kernel,
-                  impl=impl_name(use_kernel))
-        if use_kernel:
-            v, i = pq_adc_topk_pallas(luts, codes, k, block_n=block_n,
-                                      n_valid=n_valid,
-                                      interpret=not on_tpu())
-        else:
-            v, i = _pq_topk_xla(luts, codes, np.int32(n_valid), k)
-        return np.asarray(v)[:qn], np.asarray(i)[:qn]
-
     masked = probe_mask is not None
     mb = (cscores.shape[1] if cscores is not None
-          else probe_mask.shape[1] if probe_mask is not None else 1)
+          else probe_mask.shape[1] if masked else 1)
     bias = pad_rows(np.zeros(n) if bias is None else bias, rows, np.float32)
     rb = pad_rows(np.zeros(n) if row_bucket is None else row_bucket, rows,
                   np.int32)
@@ -206,8 +177,8 @@ def pq_adc_topk(luts, codes, k: int, block_n: int = 512, n_valid: int = -1,
             v, i = _pq_topk_xla_rowmask(luts, codes, np.int32(n_valid), bias,
                                         rb, cs, row_mask, k)
         return _empty_tail(v, i, qn)
-    pm = pad_rows(np.ones((qn, mb)) if probe_mask is None else probe_mask,
-                  qrows, np.float32)
+    pm = pad_rows(probe_mask if masked else np.ones((qn, mb)), qrows,
+                  np.float32)
     count_h2d(kernel, span, luts, codes, bias, rb, cs, pm, kernel=kernel,
               impl=impl_name(use_kernel))
     if use_kernel:

@@ -1,4 +1,4 @@
-"""PQ ADC scan Pallas kernel: fused LUT-sum + per-tile top-L (TPU).
+"""PQ ADC scan Pallas kernels: fused LUT-sum + per-tile top-L (TPU).
 
 The bandwidth-bound half of the PQ kNN hot loop: corpus *codes* (M bytes
 per row instead of 4d float bytes) stream HBM -> VMEM in block_n tiles;
@@ -9,7 +9,7 @@ the flattened query LUTs [Q, M*K] against it on the MXU, yielding the
 (``sweep.bitonic_topl``, L <= 128) -- no cross-tile traffic -- and a jnp
 epilogue, inside the same jitted program, merges the [n_tiles, L] partials.
 
-The *extended* kernel adds the residual / fused score decomposition
+The *extended* kernel scores the residual / fused decomposition
 
     s[q, n] = LUT sum + bias[n] + cscores[q, row_bucket[n]],
     masked to NEG where probe_mask[q, row_bucket[n]] is False
@@ -19,7 +19,9 @@ tile's bucket ids contracts against a 128-bucket block of ``cscores`` /
 ``probe_mask``.  A tile only visits the blocks its rows' buckets fall in
 (their range per tile rides in as scalar prefetch), so a code table sorted
 by bucket -- as a compacted index is -- pays for one or two blocks per tile
-instead of the whole bucket axis, and an unsorted one is still exact.
+instead of the whole bucket axis, and an unsorted one is still exact.  A
+plain ADC scan is its case of zero bias and centroid terms over one
+all-probed bucket, which ``ops.pq_adc_topk`` fills in.
 
 The *row-masked* kernel (filtered kNN) keeps the LUT sum, bias and
 centroid term and replaces the probe mask by a per-(query, row) allow bit:
@@ -89,12 +91,6 @@ def _lut_scores(luts_ref, codes_ref, ksub: int, qp: int) -> jnp.ndarray:
     return (r[2 * qp:] + r[qp:2 * qp]) + r[:qp]            # (lo+mid)+hi
 
 
-def _pq_kernel(nv_ref, luts_ref, codes_ref, vals_ref, idx_ref, *, topl: int,
-               block_n: int, ksub: int, qp: int):
-    s = _lut_scores(luts_ref, codes_ref, ksub, qp)                # [Q, BN]
-    tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
-
-
 def _bucket_terms(blo_ref, bhi_ref, rb_ref, bt_ref, acc_ref,
                   block_n: int) -> jnp.ndarray:
     """For each 128-bucket block this tile's rows fall in, one-hot the
@@ -152,22 +148,8 @@ def _pq_kernel_rowmask(nv_ref, blo_ref, bhi_ref, luts_ref, codes_ref,
     tile_topl_sorted(s, nv_ref, block_n, topl, vals_ref, idx_ref)
 
 
-def _prep(luts: jnp.ndarray, codes: jnp.ndarray, block_n: int):
-    """Shared operand prep: query rows padded to the bf16 sublane tile, the
-    LUTs flattened and split into their three bf16 pieces, the codes laid
-    out [M, N] so each tile's one-hot is a sublane broadcast."""
-    qn, m, ksub = luts.shape
-    n = codes.shape[0]
-    assert codes.shape[1] == m, (codes.shape, m)
-    assert n % block_n == 0, (n, block_n)
-    qp = -(-qn // _Q_ALIGN) * _Q_ALIGN
-    luts = jnp.pad(luts.astype(jnp.float32), ((0, qp - qn), (0, 0), (0, 0)))
-    luts3 = split3(luts.reshape(qp, m * ksub))
-    return qp, luts3, codes.astype(jnp.int32).T
-
-
 def _vmem_limit(qp: int, mk: int, block_n: int, k: int,
-                bucket_bytes: int = 0) -> int:
+                bucket_bytes: int) -> int:
     """Scoped VMEM for one call, from its shapes: the resident LUT pieces
     and bucket blocks, the tile's one-hot (float32 compare and bf16 copy),
     score pieces, bucket accumulator and sweep state, and double-buffered
@@ -180,54 +162,25 @@ def _vmem_limit(qp: int, mk: int, block_n: int, k: int,
     return int(min(max(need * 3 // 2, 32 << 20), 100 << 20))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "interpret"))
-def pq_adc_topk_pallas(luts: jnp.ndarray, codes: jnp.ndarray, k: int,
-                       block_n: int = 512, n_valid=-1,
-                       interpret: bool = True
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """[Q, M, K] x [N, M] -> (vals [Q, k], ids [Q, k]); N % block_n == 0.
-
-    ``n_valid`` (< N; traced) marks the tail rows as padding: their scores
-    are pinned to ``NEG`` inside the kernel, so the dispatcher can pad any
-    code table up to a block_n multiple without padded rows ever reaching
-    the top-k.  The caller keeps k <= n_valid."""
-    qn, m, ksub = luts.shape
-    n = codes.shape[0]
-    qp, luts3, codes_t = _prep(luts, codes, block_n)
-    n_tiles = n // block_n
-    out_specs, out_shape = tile_outputs(n_tiles, qp, k)
-
-    kernel = functools.partial(_pq_kernel, topl=k, block_n=block_n,
-                               ksub=ksub, qp=qp)
-    vals, idx = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,                       # n_valid
-            grid=(n_tiles,),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),   # LUT pieces
-                pl.BlockSpec((m, block_n), lambda i, nv: (0, i)),  # codes
-            ],
-            out_specs=out_specs),
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(qp, m * ksub, block_n, k)),
-        interpret=interpret,
-    )(n_valid_operand(n_valid, n), luts3, codes_t)
-    return merge_tiles(vals[:, :qn], idx[:, :qn], k)
-
-
 def _bucket_scan(kernel, luts, codes, bias, row_bucket, cscores, masks,
                  tiled, k: int, block_n: int, n_valid, interpret: bool):
-    """The extended scans' shared pallas_call: LUT pieces, code / bias /
+    """The two scans' shared pallas_call: LUT pieces, code / bias /
     bucket tiles and the bucket operand -- the centroid-term pieces
     followed by ``masks`` ([R, MB] rows per query: the probe mask, or
     none) cut into 128-bucket blocks -- then ``tiled``, further [rows, N]
     operands tiled along the rows (the packed row mask, or none)."""
     qn, m, ksub = luts.shape
     n = codes.shape[0]
+    assert codes.shape[1] == m, (codes.shape, m)
+    assert n % block_n == 0, (n, block_n)
     mb = cscores.shape[1]
-    qp, luts3, codes_t = _prep(luts, codes, block_n)
+    # query rows padded to the bf16 sublane tile, the LUTs flattened and
+    # split into their three bf16 pieces, the codes laid out [M, N] so each
+    # tile's one-hot is a sublane broadcast
+    qp = -(-qn // _Q_ALIGN) * _Q_ALIGN
+    luts = jnp.pad(luts.astype(jnp.float32), ((0, qp - qn), (0, 0), (0, 0)))
+    luts3 = split3(luts.reshape(qp, m * ksub))
+    codes_t = codes.astype(jnp.int32).T
     n_tiles = n // block_n
     nblk = -(-mb // BUCKET_BLOCK)
     out_specs, out_shape = tile_outputs(n_tiles, qp, k)

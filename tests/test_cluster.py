@@ -360,38 +360,32 @@ def test_stable_id_hash_is_deterministic_and_spread():
     assert counts.min() > 10_000 / 8 * 0.8          # roughly balanced
 
 
-# -- distributed_knn through the shared merge path ----------------------------
+# -- sharded ADC kNN through the shared merge path ---------------------------
 
 
 def test_distributed_knn_adc_mode():
-    """The consolidated reference schedule serves PQ shards: ADC top-k' +
-    exact re-rank per shard, merged -- identical to the global float truth
-    on a clustered corpus (re-rank recovers quantization)."""
+    """The cluster merge schedule serves PQ pieces: ADC top-k' + exact
+    re-rank per piece, merged -- identical to the global float truth on a
+    clustered corpus (re-rank recovers quantization)."""
     vecs = sift_like_vectors(1200, dim=DIM, n_clusters=12, seed=5)
     cfg = VectorIndexConfig(dim=DIM, vectors_per_bucket=1200, min_buckets=1,
                             kmeans_iters=1, pq_m=8, pq_bits=8,
                             pq_kmeans_iters=3, rerank_mult=16)
     index = IVFIndex.build(vecs, cfg=cfg, seed=0)
     import jax.numpy as jnp
+    from repro.core.vector_index import scatter_gather_knn
     rng = np.random.default_rng(6)
     q = vecs[rng.choice(1200, 5)] + \
         rng.standard_normal((5, DIM)).astype(np.float32) * 0.01
-    from repro.core.vector_index import distributed_knn
-    assign = np.arange(1200) % 4
-    shards = [index.vectors[assign == s] for s in range(4)]
-    id_shards = [index.ids[assign == s] for s in range(4)]
-    code_shards = [index.codes[assign == s] for s in range(4)]
+    pieces = index.shard(4, strategy="roundrobin")
     v_g, i_g = scan_topk(jnp.asarray(q), jnp.asarray(index.vectors),
                          jnp.asarray(index.ids), 8, "l2")
-    v_d, i_d = distributed_knn(q, shards, id_shards, 8, "l2",
-                               mode="adc", pq=index.pq,
-                               code_shards=code_shards)
-    assert np.array_equal(np.asarray(i_g), np.asarray(i_d))
+    v_d, i_d = scatter_gather_knn(pieces, q, 8, mode="adc")
+    assert np.array_equal(np.asarray(i_g), i_d)
     # scores to fp32 noise: the global truth uses the matmul-identity L2 on
     # device, the re-rank computes the difference form on host -- near-zero
     # distances keep ~1e-3 of cancellation noise on ~1e1 magnitudes
-    np.testing.assert_allclose(np.asarray(v_g), np.asarray(v_d),
-                               rtol=1e-4, atol=5e-3)
+    np.testing.assert_allclose(np.asarray(v_g), v_d, rtol=1e-4, atol=5e-3)
 
 
 # -- cost model: shard terms --------------------------------------------------
@@ -596,16 +590,17 @@ def test_scatter_gather_padding_contract_starved_shards():
     (one completely empty).  The merged output must satisfy the padding
     invariant -- id=-1 exactly where val=-inf, never a -1 with a finite
     score and never a real id past the real candidate count."""
-    from repro.core.vector_index import scatter_gather_knn, flat_shard_view
+    from repro.core.vector_index import scatter_gather_knn
 
     rng = np.random.default_rng(21)
     qs = rng.standard_normal((5, 8)).astype(np.float32)
     rows = rng.standard_normal((5, 8)).astype(np.float32)
-    shards = [
-        flat_shard_view(rows[:2], np.asarray([10, 11])),
-        flat_shard_view(rows[2:2], np.asarray([], np.int64)),  # empty shard
-        flat_shard_view(rows[2:], np.asarray([12, 13, 14])),
-    ]
+    cfg = VectorIndexConfig(dim=8, metric="l2", min_buckets=1,
+                            vectors_per_bucket=5, nprobe=1)
+    index = IVFIndex.build(rows, ids=np.arange(10, 15), cfg=cfg)
+    # ids 10, 11 on piece 0, none on piece 1, ids 12-14 on piece 2
+    shards = index.shard(3, assign=np.where(index.ids < 12, 0, 2))
+    assert [s.n_total for s in shards] == [2, 0, 3]
     k = 10                                   # > 5 total real rows
     v, i = scatter_gather_knn(shards, qs, k)
     assert v.shape == (5, k) and i.shape == (5, k)

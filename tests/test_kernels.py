@@ -12,7 +12,7 @@ from repro.kernels.ivf_scan.ivf_scan import ivf_scan_topk_pallas
 from repro.kernels.ivf_scan.ops import ivf_scan_topk
 from repro.kernels.ivf_scan.ref import ivf_scan_topk_ref
 from repro.kernels.pq_scan.ops import pq_adc_topk
-from repro.kernels.pq_scan.pq_scan import pq_adc_topk_pallas
+from repro.kernels.pq_scan.pq_scan import pq_adc_topk_ext_pallas
 from repro.kernels.pq_scan.ref import pq_adc_topk_ref, pq_scores_ref
 
 RNG = np.random.default_rng(0)
@@ -101,13 +101,22 @@ def _pq_inputs(qn, n, m, ksub):
     return luts, codes
 
 
+def _zero_terms(qn, n):
+    """The extended kernel's operands for a plain ADC scan: zero bias and
+    centroid terms, every row in one probed bucket."""
+    return (jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.int32),
+            jnp.zeros((qn, 1), jnp.float32), jnp.ones((qn, 1), jnp.float32))
+
+
 @pytest.mark.parametrize("qn,n,m,ksub,k", [(1, 512, 4, 16, 1),
                                            (4, 1024, 8, 256, 8),
                                            (16, 2048, 16, 256, 16),
                                            (8, 512, 8, 64, 32)])
 def test_pq_scan_shapes(qn, n, m, ksub, k):
+    """The extended kernel with zero terms is the plain ADC scan."""
     luts, codes = _pq_inputs(qn, n, m, ksub)
-    v1, i1 = pq_adc_topk_pallas(luts, codes, k, interpret=True)
+    v1, i1 = pq_adc_topk_ext_pallas(luts, codes, *_zero_terms(qn, n), k,
+                                    interpret=True)
     v2, i2 = pq_adc_topk_ref(luts, codes, k)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
                                rtol=1e-4, atol=1e-4)
@@ -125,8 +134,11 @@ def test_pq_scores_match_manual_gather():
 
 
 def test_pq_ops_fallback_large_k():
+    from repro.kernels.dispatch import dispatch_counts
     luts, codes = _pq_inputs(2, 1024, 4, 16)
+    before = dispatch_counts().get("pq_scan_ext:xla", 0)
     v, i = pq_adc_topk(luts, codes, k=500)     # falls back to XLA path
+    assert dispatch_counts()["pq_scan_ext:xla"] == before + 1
     v2, i2 = pq_adc_topk_ref(luts, codes, 500)
     assert np.array_equal(np.asarray(i), np.asarray(i2))
 
@@ -134,9 +146,13 @@ def test_pq_ops_fallback_large_k():
 @pytest.mark.parametrize("n", [100, 513, 777, 1500])
 def test_pq_ops_pads_to_kernel(n):
     """n % block_n != 0 must still hit the kernel: the wrapper pads the
-    code table and masks the padding via n_valid, parity with the oracle."""
+    code table and masks the padding via n_valid, parity with the oracle.
+    Without residual terms the call takes the extended kernel."""
+    from repro.kernels.dispatch import dispatch_counts
     luts, codes = _pq_inputs(4, n, 8, 256)
+    before = dispatch_counts().get("pq_scan_ext:pallas", 0)
     v1, i1 = pq_adc_topk(luts, codes, 8, force_pallas=True)
+    assert dispatch_counts()["pq_scan_ext:pallas"] == before + 1
     v2, i2 = pq_adc_topk_ref(luts, codes, 8)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
                                rtol=1e-4, atol=1e-4)
@@ -157,8 +173,9 @@ def test_pq_pallas_n_valid_masks_tail():
     n_real, n_pad = 700, 1024
     luts, codes = _pq_inputs(3, n_real, 4, 16)
     c_pad = jnp.pad(codes, ((0, n_pad - n_real), (0, 0)))
-    v1, i1 = pq_adc_topk_pallas(luts, c_pad, 5, block_n=512,
-                                n_valid=n_real, interpret=True)
+    v1, i1 = pq_adc_topk_ext_pallas(luts, c_pad, *_zero_terms(3, n_pad), 5,
+                                    block_n=512, n_valid=n_real,
+                                    interpret=True)
     v2, i2 = pq_adc_topk_ref(luts, codes, 5)
     np.testing.assert_allclose(np.asarray(v1), np.asarray(v2),
                                rtol=1e-4, atol=1e-4)
@@ -561,35 +578,37 @@ def test_pq_ext_requires_row_bucket():
 
 # -- dispatch counters -------------------------------------------------------
 
-def _dispatch_call(kernel, k):
-    if kernel == "ivf_scan":
+def _dispatch_call(call, k):
+    if call == "ivf_scan":
         ivf_scan_topk(RNG.standard_normal((2, 32)).astype(np.float32),
                       RNG.standard_normal((600, 32)).astype(np.float32), k,
                       force_pallas=True)
-    elif kernel == "topk_merge":
+    elif call == "topk_merge":
         vals, ids = _merge_inputs(2, 3, 80, seed=k)
         merge_topk_dev(jnp.asarray(vals), jnp.asarray(ids), k,
                        force_pallas=True)
     else:
         luts, codes, bias, rb, cs, _ = _ext_inputs(2, 600, 8, 64, 4, seed=k)
         ext = (dict(bias=bias, row_bucket=rb, cscores=cs)
-               if kernel == "pq_scan_ext" else {})
+               if call == "pq_scan_ext" else {})
         pq_adc_topk(luts, codes, k, force_pallas=True, **ext)
 
 
-@pytest.mark.parametrize("kernel", ["ivf_scan", "pq_scan", "pq_scan_ext",
-                                    "topk_merge"])
+@pytest.mark.parametrize("call", ["ivf_scan", "pq_scan", "pq_scan_ext",
+                                  "topk_merge"])
 @pytest.mark.parametrize("k", [8, 80, 129])
-def test_dispatch_counts_each_implementation(kernel, k):
+def test_dispatch_counts_each_implementation(call, k):
     """force_pallas takes the kernel up to its own cap (64; 128 for the
-    extended PQ scan) and the XLA twin above it; each call counts once under
-    the implementation it took, and a Pallas call off the chip also counts
-    as interpreted."""
+    PQ scan) and the XLA twin above it; each call counts once under the
+    implementation it took, and a Pallas call off the chip also counts
+    as interpreted.  A PQ call without residual terms (``pq_scan``)
+    counts under ``pq_scan_ext``."""
     from repro.kernels.dispatch import KERNEL_MAX_K, dispatch_counts
+    kernel = "pq_scan_ext" if call.startswith("pq_scan") else call
     impl = "pallas" if k <= KERNEL_MAX_K[kernel] else "xla"
     assert KERNEL_MAX_K[kernel] == (128 if kernel == "pq_scan_ext" else 64)
     before = dispatch_counts()
-    _dispatch_call(kernel, k)
+    _dispatch_call(call, k)
     after = dispatch_counts()
     delta = {key: after[key] - before.get(key, 0) for key in after
              if key.startswith(f"{kernel}:")

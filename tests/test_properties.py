@@ -23,17 +23,20 @@ SETTINGS = dict(max_examples=25, deadline=None)
     seed=st.integers(0, 2**31 - 1),
 )
 def test_distributed_topk_equals_global(n, k, shards, seed):
-    from repro.core.vector_index import distributed_knn, scan_topk
+    from repro.configs.pandadb import VectorIndexConfig
+    from repro.core.vector_index import (IVFIndex, scan_topk,
+                                         scatter_gather_knn)
     rng = np.random.default_rng(seed)
-    corpus = jnp.asarray(rng.standard_normal((n, 8)), jnp.float32)
-    ids = jnp.arange(n)
-    q = jnp.asarray(rng.standard_normal((2, 8)), jnp.float32)
-    v_g, _ = scan_topk(q, corpus, ids, k, "l2")
-    cs = [corpus[i::shards] for i in range(shards)]
-    iss = [ids[i::shards] for i in range(shards)]
-    v_d, _ = distributed_knn(q, cs, iss, k, "l2")
-    np.testing.assert_allclose(np.asarray(v_g), np.asarray(v_d), rtol=1e-4,
-                               atol=1e-4)
+    corpus = rng.standard_normal((n, 8)).astype(np.float32)
+    q = rng.standard_normal((2, 8)).astype(np.float32)
+    v_g, _ = scan_topk(jnp.asarray(q), jnp.asarray(corpus), jnp.arange(n),
+                       k, "l2")
+    cfg = VectorIndexConfig(dim=8, metric="l2", min_buckets=1,
+                            vectors_per_bucket=n, nprobe=1)
+    pieces = IVFIndex.build(corpus, cfg=cfg, seed=seed).shard(
+        shards, strategy="roundrobin")
+    v_d, _ = scatter_gather_knn(pieces, q, k)
+    np.testing.assert_allclose(np.asarray(v_g), v_d, rtol=1e-4, atol=1e-4)
 
 
 # -- IVF invariants --------------------------------------------------------------
@@ -69,8 +72,8 @@ def test_ivf_partition_is_total(n, seed):
     seed=st.integers(0, 1000),
 )
 def test_batched_search_equals_per_query(n, qn, k, nprobe, seed):
-    """Grouping queries by probe signature (or taking the masked dense
-    scan) must return exactly what one-query-at-a-time searches return."""
+    """Grouping queries by probe signature must return exactly what
+    one-query-at-a-time searches return."""
     from repro.configs.pandadb import VectorIndexConfig
     from repro.core.vector_index import IVFIndex
     rng = np.random.default_rng(seed)

@@ -21,7 +21,6 @@ import pytest
 from repro.kernels.ivf_scan.ivf_scan import ivf_scan_topk_pallas
 from repro.kernels.pq_scan.ops import row_mask_from_bitmaps
 from repro.kernels.pq_scan.pq_scan import (pq_adc_topk_ext_pallas,
-                                           pq_adc_topk_pallas,
                                            pq_adc_topk_rowmask_pallas)
 from repro.kernels.topk_merge.topk_merge import merge_topk_pallas
 
@@ -53,8 +52,21 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kernel_cases(qn, k, sh):
+def _pq_ext_case(qn, k, mb, sh):
+    """The extended PQ scan over ``mb`` buckets (1: the operands a call
+    without residual terms fills in)."""
     f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    return (
+        lambda l, c, b, rb, cs, pm, nv: pq_adc_topk_ext_pallas(
+            l, c, b, rb, cs, pm, k, n_valid=nv, interpret=False),
+        (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
+         _spec((N,), f32, sh), _spec((N,), i32, sh),
+         _spec((qn, mb), f32, sh), _spec((qn, mb), f32, sh),
+         _spec((), i32, sh)))
+
+
+def _kernel_cases(qn, k, sh):
+    f32, i32 = jnp.float32, jnp.int32
     return {
         "ivf_scan": (
             lambda q, c, nv: ivf_scan_topk_pallas(q, c, k, metric="l2",
@@ -62,18 +74,8 @@ def _kernel_cases(qn, k, sh):
                                                   interpret=False),
             (_spec((qn, D), f32, sh), _spec((N, D), f32, sh),
              _spec((), i32, sh))),
-        "pq_scan": (
-            lambda l, c, nv: pq_adc_topk_pallas(l, c, k, n_valid=nv,
-                                                interpret=False),
-            (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
-             _spec((), i32, sh))),
-        "pq_scan_ext": (
-            lambda l, c, b, rb, cs, pm, nv: pq_adc_topk_ext_pallas(
-                l, c, b, rb, cs, pm, k, n_valid=nv, interpret=False),
-            (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
-             _spec((N,), f32, sh), _spec((N,), i32, sh),
-             _spec((qn, MB), f32, sh), _spec((qn, MB), f32, sh),
-             _spec((), i32, sh))),
+        "pq_scan": _pq_ext_case(qn, k, 1, sh),      # no residual terms
+        "pq_scan_ext": _pq_ext_case(qn, k, MB, sh),
         "topk_merge": (
             lambda v, i: merge_topk_pallas(v, i, k, interpret=False),
             # the wrapper pads the query axis up to a block_q (128) multiple
@@ -101,14 +103,7 @@ def test_pq_scan_ext_compiles_at_served_shape(one_chip):
     N 131 072, 1 420 buckets, k' 80.  Its resident LUT pieces and bucket
     blocks pass the default scoped VMEM limit, so an overrun at the served
     shape fails here rather than on the chip."""
-    f32, i32, u8, sh = jnp.float32, jnp.int32, jnp.uint8, one_chip
-    qn, mb, k = 256, 1420, 80
-    fn = lambda l, c, b, rb, cs, pm, nv: pq_adc_topk_ext_pallas(  # noqa: E731
-        l, c, b, rb, cs, pm, k, n_valid=nv, interpret=False)
-    args = (_spec((qn, M, KSUB), f32, sh), _spec((N, M), u8, sh),
-            _spec((N,), f32, sh), _spec((N,), i32, sh),
-            _spec((qn, mb), f32, sh), _spec((qn, mb), f32, sh),
-            _spec((), i32, sh))
+    fn, args = _pq_ext_case(256, 80, 1420, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

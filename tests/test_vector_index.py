@@ -8,11 +8,11 @@ import jax.numpy as jnp
 from repro.configs.pandadb import VectorIndexConfig
 from repro.core.vector_index import (
     IVFIndex,
-    distributed_knn,
     merge_topk,
     pairwise_scores,
     recall_at_k,
     scan_topk,
+    scatter_gather_knn,
 )
 from repro.data.synthetic_graph import sift_like_vectors
 
@@ -122,7 +122,8 @@ def test_batched_matches_loop_clustered(index):
 
 
 def test_batched_matches_loop_scattered(index):
-    """Scattered signatures take the masked dense scan: same candidates."""
+    """Scattered signatures, one gathered scan per signature: same
+    candidates."""
     rng = np.random.default_rng(8)
     queries = rng.standard_normal((64, 32)).astype(np.float32)
     for k, nprobe in [(10, 4), (10, 8)]:
@@ -189,7 +190,7 @@ def test_exact_mode_byte_identical(index):
 
 
 def test_insert_then_search_uncompacted():
-    """Uncompacted buffer rows participate in probe, exact and dense
+    """Uncompacted buffer rows participate in probe, exact and batched
     searches; compaction changes nothing observable."""
     vecs = sift_like_vectors(600, dim=16, n_clusters=8, seed=5)
     cfg = VectorIndexConfig(dim=16, metric="l2", vectors_per_bucket=100,
@@ -206,7 +207,7 @@ def test_insert_then_search_uncompacted():
         assert ids[0, 0] == 10_000 + j          # exact mode must find it
     _, ids_exact = idx.search_exact(new, 1)
     assert set(ids_exact[:, 0].tolist()) == set(range(10_000, 10_020))
-    # dense masked path sees pending rows too
+    # the batched signature-group path sees pending rows too
     queries = rng.standard_normal((32, 16)).astype(np.float32)
     v_pend, i_pend = idx.search_many(queries, 5, 3)
     idx.compact()
@@ -248,37 +249,47 @@ def test_pending_compaction_threshold():
     assert np.all(np.diff(idx.bucket_of) >= 0)
 
 
+def _flat_pieces(corpus, ids, n_shards):
+    """``n_shards`` pieces of a one-bucket IVF-Flat index over ``corpus``,
+    row r on piece r % n_shards: the layout ``ShardedPandaDB`` serves."""
+    corpus = np.asarray(corpus, np.float32)
+    cfg = VectorIndexConfig(dim=corpus.shape[1], metric="l2", min_buckets=1,
+                            vectors_per_bucket=len(corpus), nprobe=1)
+    index = IVFIndex.build(corpus, ids=np.asarray(ids), cfg=cfg)
+    return index.shard(n_shards, strategy="roundrobin")
+
+
 def test_distributed_knn_equals_global():
+    """Sharded kNN (per-piece scan, k-way merge) equals the global scan."""
     rng = np.random.default_rng(4)
     corpus = jnp.asarray(rng.standard_normal((1024, 16)), jnp.float32)
     ids = jnp.arange(1024)
     q = jnp.asarray(rng.standard_normal((5, 16)), jnp.float32)
     v_g, i_g = scan_topk(q, corpus, ids, 8, "l2")
-    shards = [corpus[i::4] for i in range(4)]
-    id_shards = [ids[i::4] for i in range(4)]
-    v_d, i_d = distributed_knn(q, shards, id_shards, 8, "l2")
-    np.testing.assert_allclose(np.asarray(v_g), np.asarray(v_d), rtol=1e-5)
-    assert np.array_equal(np.asarray(i_g), np.asarray(i_d))
+    pieces = _flat_pieces(corpus, ids, 4)
+    v_d, i_d = scatter_gather_knn(pieces, np.asarray(q), 8)
+    np.testing.assert_allclose(np.asarray(v_g), v_d, rtol=1e-5)
+    assert np.array_equal(np.asarray(i_g), i_d)
 
 
 def test_distributed_knn_no_sentinel_leak():
-    """Shards smaller than k pad with (-inf, -1); the merge must never show
-    those to callers when enough real candidates exist, and must truncate
-    when they don't."""
+    """Pieces smaller than k pad with (-inf, -1); the merge must never show
+    those to callers when enough real candidates exist, and past the real
+    rows every slot is (-inf, -1), never a -1 with a finite score."""
     rng = np.random.default_rng(11)
-    corpus = jnp.asarray(rng.standard_normal((10, 4)), jnp.float32)
-    ids = jnp.arange(10)
-    q = jnp.asarray(rng.standard_normal((3, 4)), jnp.float32)
-    # 4 shards of 2-3 rows, k=8 > any shard: total rows (10) >= k -> no -1
-    shards = [corpus[i::4] for i in range(4)]
-    id_shards = [ids[i::4] for i in range(4)]
-    v, i = distributed_knn(q, shards, id_shards, 8, "l2")
-    assert np.all(np.asarray(i) >= 0)
-    assert np.all(np.isfinite(np.asarray(v)))
-    # total rows (10) < k=20 -> truncated to 10 columns, still no -1
-    v, i = distributed_knn(q, shards, id_shards, 20, "l2")
-    assert v.shape == (3, 10) and i.shape == (3, 10)
-    assert np.all(np.asarray(i) >= 0)
+    corpus = rng.standard_normal((10, 4)).astype(np.float32)
+    q = rng.standard_normal((3, 4)).astype(np.float32)
+    # 4 pieces of 2-3 rows, k=8 > any piece: total rows (10) >= k -> no -1
+    pieces = _flat_pieces(corpus, np.arange(10), 4)
+    assert max(p.n_total for p in pieces) < 8
+    v, i = scatter_gather_knn(pieces, q, 8)
+    assert np.all(i >= 0)
+    assert np.all(np.isfinite(v))
+    # total rows (10) < k=20 -> 10 real columns, then padding only
+    v, i = scatter_gather_knn(pieces, q, 20)
+    assert v.shape == (3, 20) and i.shape == (3, 20)
+    assert np.all(i[:, :10] >= 0) and np.all(np.isfinite(v[:, :10]))
+    assert np.all(i[:, 10:] == -1) and np.all(np.isinf(v[:, 10:]))
 
 
 def test_merge_topk_associative():
